@@ -13,9 +13,12 @@ the paper's network as its one-stage special case.
 
 Run:  PYTHONPATH=src python examples/quickstart.py
       PYTHONPATH=src python examples/quickstart.py cluster   # real subprocesses
+(on a CPU-only machine prefix both with REPRO_INTERPRET=1)
 
 Instance sizes are env-tunable (CI's examples-smoke job shrinks them):
-QUICKSTART_WIDTH / QUICKSTART_LINES / QUICKSTART_ITERS.
+QUICKSTART_WIDTH / QUICKSTART_LINES / QUICKSTART_ITERS.  The Mandelbrot
+kernel runs compiled, which needs a TPU; ``REPRO_INTERPRET=1`` runs it
+in the Pallas interpreter instead, as a CPU-only machine must.
 """
 
 import os
@@ -33,6 +36,7 @@ from repro.kernels.mandelbrot.ref import line_coords
 WIDTH = int(os.environ.get("QUICKSTART_WIDTH", "700"))    # paper: 5600
 LINES = int(os.environ.get("QUICKSTART_LINES", "400"))    # paper: 3200
 MAX_ITERATIONS = int(os.environ.get("QUICKSTART_ITERS", "250"))  # paper: 1000
+INTERPRET = os.environ.get("REPRO_INTERPRET", "0") == "1"
 
 SPEC = """
 # Mandelbrot DSL specification (paper Listing 2), python-flavoured .cgpp
@@ -71,7 +75,8 @@ collector = Collect(r_details=result_details)
 def calculate(line_y: int):
     """The user's sequential data method (paper Mdata.calculateColour)."""
     x0, y0 = line_coords(WIDTH, line_y)
-    iters, colour = mandelbrot(x0[None], y0[None], max_iters=MAX_ITERATIONS)
+    iters, colour = mandelbrot(x0[None], y0[None], max_iters=MAX_ITERATIONS,
+                               interpret=INTERPRET)
     return {
         "points": WIDTH,
         "white": int(jnp.sum(colour)),

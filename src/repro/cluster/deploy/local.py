@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import collections
 import os
+import pathlib
 import subprocess
 import sys
 import threading
@@ -23,20 +24,36 @@ from typing import Sequence
 from repro.cluster.deploy.base import Launcher, NodeHandle
 
 
-def jax_node_env(compile_cache_dir: str | None = None) -> dict[str, str]:
-    """The env overlay every node-loader needs, whatever launches it.
+# The compile cache's fixed home when JAX_COMPILATION_CACHE_DIR is unset:
+# inside the checkout (gitignored), resolved from this file and not from
+# the working directory, so every process of a run finds the same one.
+DEFAULT_COMPILE_CACHE = str(
+    pathlib.Path(__file__).resolve().parents[4] / "results" / "xla_cache")
 
-    Node-loaders are bootstrap processes: keep their (transitive) jax happy
-    on CPU-only machines.  With ``compile_cache_dir``, a cluster-wide XLA
-    compilation cache: the host's warm-up compile lands on disk and every
-    node-loader loads the binary instead of recompiling — the paper's
-    single-source code-shipping idea applied to executables.  One recipe
+
+def compile_cache_dir() -> str:
+    """``JAX_COMPILATION_CACHE_DIR`` where it is set, else the fixed
+    :data:`DEFAULT_COMPILE_CACHE`."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_COMPILE_CACHE
+
+
+def jax_node_env(compile_cache_dir: str | None = None) -> dict[str, str]:
+    """The env overlay every node-loader gets, whatever launches it.
+
+    It sets no platform: a node runs on whatever ``JAX_PLATFORMS`` its
+    launcher's environment holds (tests keep the CPU that way, a TPU host
+    leaves it unset).  With a compile cache — the caller's
+    ``JAX_COMPILATION_CACHE_DIR`` where it is set, else
+    ``compile_cache_dir`` — every node-loader of the cluster reads and
+    writes that one persistent XLA cache, so a node compiles a kernel for
+    its own device once and later processes load the binary.  One recipe
     shared by every launcher (local subprocess env, ssh ``env`` exports),
     so a knob added here reaches remote nodes too.
     """
-    env = {"JAX_PLATFORMS": "cpu"}
-    if compile_cache_dir:
-        env["JAX_COMPILATION_CACHE_DIR"] = compile_cache_dir
+    env: dict[str, str] = {}
+    cache = os.environ.get("JAX_COMPILATION_CACHE_DIR") or compile_cache_dir
+    if cache:
+        env["JAX_COMPILATION_CACHE_DIR"] = cache
         env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
     return env
 
@@ -45,10 +62,7 @@ def _child_env(compile_cache_dir: str | None = None) -> dict[str, str]:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(p for p in sys.path if p)
     for key, val in jax_node_env(compile_cache_dir).items():
-        if key == "JAX_COMPILATION_CACHE_DIR":
-            env[key] = val  # the shared cache is authoritative
-        else:
-            env.setdefault(key, val)  # respect the caller's environment
+        env.setdefault(key, val)  # respect the caller's environment
     return env
 
 

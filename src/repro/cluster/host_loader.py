@@ -324,7 +324,8 @@ class HostLoader:
         self.total_nodes = total
         self.timing = timing or TimingCollector()
         self.host = host
-        self.membership = Membership(heartbeat or HeartbeatMonitor())
+        self.membership = Membership(heartbeat or HeartbeatMonitor(),
+                                     boot_grace_s=register_timeout)
         self.register_timeout = register_timeout
         self.placement = placement or PlacementPolicy()
         self.placement.validate(total)
@@ -643,8 +644,9 @@ class HostLoader:
                                     p.get("acks") or [],
                                     int(p.get("credits", 0)))
                 elif frame.ftype is FrameType.HEARTBEAT:
-                    self.membership.beat(node_id)
                     rep = (frame.payload or {}).get("report")
+                    self.membership.beat(
+                        node_id, booting=bool((rep or {}).get("booting")))
                     if rep:
                         # Node-side phase/cache counters piggybacked on the
                         # beat (kept as the slow fallback channel).
@@ -672,8 +674,9 @@ class HostLoader:
                 self._reap()
             elif kind == "disconnect":
                 # The socket died; death itself is declared by the
-                # heartbeat threshold (reap), keeping one detection path.
-                pass
+                # heartbeat threshold (reap), keeping one detection path —
+                # without a boot's grace, since no boot ends now.
+                self.membership.end_boot(event[1])
             elif kind == "register":
                 # Late join: a node registering after the run started is
                 # shipped LOAD immediately (the per-registration LOAD
@@ -701,6 +704,7 @@ class HostLoader:
                         pid=int(payload.get("pid", 0)),
                         conn=conn,
                         peer_port=int(payload.get("peer_port", 0)),
+                        booting=bool(payload.get("booting")),
                     )
                 except ValueError:
                     conn.close()  # duplicate of a live member
@@ -1499,9 +1503,11 @@ class HostLoader:
                 # into the dispatcher once bootstrap completes.
                 _, node_id, frame = event
                 if frame.ftype in (FrameType.HEARTBEAT, FrameType.REPORT):
-                    if frame.ftype is FrameType.HEARTBEAT:
-                        self.membership.beat(node_id)
                     rep = (frame.payload or {}).get("report")
+                    if frame.ftype is FrameType.HEARTBEAT:
+                        self.membership.beat(
+                            node_id,
+                            booting=bool((rep or {}).get("booting")))
                     if rep:
                         self.telemetry.set_node(node_id, report=rep)
                 elif frame.ftype is FrameType.BLOCK_REQUEST:
@@ -1516,6 +1522,9 @@ class HostLoader:
                 # admission happens in the dispatcher, after the barrier.
                 self._early_events.append(event)
                 continue
+            if event[0] == "disconnect":
+                self.membership.end_boot(event[1])
+                continue
             if event[0] != "register":
                 continue  # pre-bootstrap noise
             _, node_id, addr, conn, payload = event
@@ -1526,6 +1535,7 @@ class HostLoader:
                     pid=int(payload.get("pid", 0)),
                     conn=conn,
                     peer_port=int(payload.get("peer_port", 0)),
+                    booting=bool(payload.get("booting")),
                 )
             except ValueError:
                 conn.close()  # duplicate node_id: reject it, keep waiting

@@ -86,6 +86,12 @@ class NodeRecord:
     # reported; the node is unreachable for peer routing / block trading
     # and routing tables simply omit it).
     peer_port: int = 0
+    # Still booting (REGISTER said so; the node's beats clear it once its
+    # preload and backend start are done).  Missed beats do not count
+    # while it is set, for at most ``Membership.boot_grace_s`` after
+    # registration: a backend start can hold the node's interpreter lock,
+    # and with it the beacon, for seconds.  A booting node holds no work.
+    booting: bool = False
 
     @property
     def alive(self) -> bool:
@@ -95,8 +101,10 @@ class NodeRecord:
 class Membership:
     """The HNL's view of the cluster, with heartbeat-based death detection."""
 
-    def __init__(self, monitor: HeartbeatMonitor | None = None):
+    def __init__(self, monitor: HeartbeatMonitor | None = None, *,
+                 boot_grace_s: float = 30.0):
         self.monitor = monitor or HeartbeatMonitor()
+        self.boot_grace_s = boot_grace_s
         self.nodes: dict[str, NodeRecord] = {}
         self.failures: list[FailureEvent] = []
         # Observability hook: called as on_transition(rec, old_state) after
@@ -149,6 +157,7 @@ class Membership:
 
     def register(self, node_id: str, address: str, *, cores: int = 1,
                  pid: int = 0, conn: Any = None, peer_port: int = 0,
+                 booting: bool = False,
                  now: float | None = None) -> NodeRecord:
         now = time.monotonic() if now is None else now
         rec = self.nodes.get(node_id)
@@ -163,6 +172,7 @@ class Membership:
             rec.pid = pid
             rec.conn = conn
             rec.peer_port = peer_port
+            rec.booting = booting
             rec.registered_at = rec.last_beat = now
             self._transition(rec, REGISTERED, now)
             return rec
@@ -177,6 +187,7 @@ class Membership:
             last_beat=now,
             conn=conn,
             peer_port=peer_port,
+            booting=booting,
             state=LAUNCHING,
         )
         self.nodes[node_id] = rec
@@ -193,10 +204,12 @@ class Membership:
         self._transition(rec, REPLACED)
         return rec
 
-    def beat(self, node_id: str, now: float | None = None) -> None:
+    def beat(self, node_id: str, now: float | None = None, *,
+             booting: bool = False) -> None:
         rec = self.nodes.get(node_id)
         if rec is None or not rec.alive:
             return  # late beat from an already-reaped node: ignore
+        rec.booting = booting
         rec.last_beat = time.monotonic() if now is None else now
         rec.beats += 1
 
@@ -228,12 +241,23 @@ class Membership:
 
     # -- liveness -----------------------------------------------------------
 
+    def end_boot(self, node_id: str) -> None:
+        """The node's connection closed: a node that was still booting
+        is held to the plain heartbeat deadline from now on."""
+        rec = self.nodes.get(node_id)
+        if rec is not None:
+            rec.booting = False
+
     def reap(self, now: float | None = None, *, at_item: int = 0
              ) -> list[NodeRecord]:
-        """Declare nodes whose heartbeats exceeded the threshold dead."""
+        """Declare nodes whose heartbeats exceeded the threshold dead; a
+        node still booting only once ``boot_grace_s`` has passed since it
+        registered."""
         now = time.monotonic() if now is None else now
         newly_dead = []
         for rec in self.nodes.values():
+            if rec.booting and now - rec.registered_at <= self.boot_grace_s:
+                continue
             if rec.alive and self.monitor.is_dead(rec.last_beat, now):
                 self.mark_dead(rec.node_id, at_item=at_item, now=now)
                 newly_dead.append(rec)

@@ -11,7 +11,9 @@ Lifecycle (timed per requirement 7, split three ways):
 1. *boot*: connect + REGISTER (node id, cores, pid) on the load channel
    while a background thread pre-imports heavy dependencies named on the
    command line (``--preload jax.numpy``) — the environment cost of the
-   workstation, accounted separately from code distribution.  The dial
+   workstation, accounted separately from code distribution.  A preload
+   that brings in JAX starts its backend too (the node's device), and the
+   host counts no missed heartbeats until the boot is done.  The dial
    retries with exponential backoff inside ``--connect-timeout``: a
    remotely launched node may come up before the host is listening;
 2. *load*: receive LOAD frames — the deployment payload (work functions
@@ -59,6 +61,7 @@ import os
 import queue
 import random
 import socket
+import sys
 import threading
 import time
 import traceback
@@ -157,15 +160,35 @@ def run_node(
     node_id = node_id or f"{socket.gethostname()}-{os.getpid()}"
     t_boot0 = time.perf_counter()
 
+    # Node-side telemetry, piggybacked on every beat — the only node->host
+    # reporting channel that exists before UT.  Mutated in place by the
+    # boot/load/worker paths (single-value updates; a torn read costs
+    # nothing).  ``booting`` stays true until the preload (and the backend
+    # start below) is done: the host counts no missed beats until then.
+    report = {"booting": bool(preload), "boot_ms": 0.0, "backend_ms": None,
+              "load_ms": 0.0, "items": 0, "cache_hits": 0,
+              "cache_misses": 0, "jobs_bound": 0}
+
     # Heavy dependencies import concurrently with registration: the cost of
     # booting the environment lands in boot_ms, not in the code-distribution
-    # (load) window the paper accounts in §8.2.
+    # (load) window the paper accounts in §8.2.  A preload that brought in
+    # JAX also starts its backend here, inside boot and not in a job's
+    # first item: a TPU client's start takes seconds and can hold the
+    # interpreter lock throughout, so no heartbeat leaves meanwhile.
     def preloader() -> None:
         for name in preload:
             try:
                 importlib.import_module(name)
             except Exception:  # the shipped code will surface a real error
                 pass
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            t0 = time.perf_counter()
+            try:
+                jax.devices()
+            except Exception:  # likewise: the first job reports it
+                return
+            report["backend_ms"] = round((time.perf_counter() - t0) * 1e3, 3)
 
     preload_thread = threading.Thread(target=preloader, name="nl-preload",
                                       daemon=True)
@@ -191,7 +214,8 @@ def run_node(
     conn.send(Frame(
         FrameType.REGISTER,
         {"node_id": node_id, "cores": os.cpu_count() or 1,
-         "pid": os.getpid(), "peer_port": peer_server.port},
+         "pid": os.getpid(), "peer_port": peer_server.port,
+         "booting": report["booting"]},
         LOAD_WIRE_CHANNEL,
     ))
 
@@ -201,12 +225,6 @@ def run_node(
     stop_beat = threading.Event()
     beat_interval = [DEFAULT_HEARTBEAT_S]
 
-    # Node-side telemetry, piggybacked on every beat — the only node->host
-    # reporting channel that exists before UT.  Mutated in place by the
-    # load/worker paths (single-value updates; a torn read costs nothing).
-    report = {"boot_ms": 0.0, "load_ms": 0.0, "items": 0,
-              "cache_hits": 0, "cache_misses": 0, "jobs_bound": 0}
-
     def snapshot_report() -> dict:
         rep = dict(report)
         rep.update(peer_server.counters())
@@ -215,14 +233,17 @@ def run_node(
         rep["peer_bytes_sent"] = peer_client.bytes_sent
         return rep
 
+    def beat() -> None:
+        conn.send(Frame(
+            FrameType.HEARTBEAT,
+            {"node_id": node_id, "report": snapshot_report()},
+            LOAD_WIRE_CHANNEL,
+        ))
+
     def heartbeat() -> None:
         while not stop_beat.wait(beat_interval[0]):
             try:
-                conn.send(Frame(
-                    FrameType.HEARTBEAT,
-                    {"node_id": node_id, "report": snapshot_report()},
-                    LOAD_WIRE_CHANNEL,
-                ))
+                beat()
             except OSError:
                 return
 
@@ -236,6 +257,12 @@ def run_node(
     preload_thread.join()
     boot_ms = (time.perf_counter() - t_boot0) * 1e3
     report["boot_ms"] = round(boot_ms, 3)
+    report["booting"] = False
+    if preload:
+        try:
+            beat()  # the host counts missed beats again from now
+        except OSError:
+            pass  # the host is gone: the load loop below finds out
     load_ms = 0.0
     items_done = 0
     run_ms = 0.0

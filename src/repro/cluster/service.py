@@ -186,7 +186,11 @@ class ClusterService:
     """A long-lived node pool multiplexing many jobs (see module docstring).
 
     Construction is cheap; ``start()`` (or the first ``submit``, or
-    entering the context manager) boots the pool.
+    entering the context manager) boots the pool.  ``preload`` names
+    modules each node imports while it boots; jobs that run JAX on an
+    accelerator should name one that imports jax, so that the node starts
+    its backend during boot, where missed heartbeats do not count, and not
+    inside a job's first item.
     """
 
     def __init__(
@@ -298,9 +302,14 @@ class ClusterService:
                 self.launcher = SSHLauncher(self.hosts,
                                             preload=self.preload)
             else:
-                from repro.cluster.deploy.local import LocalLauncher
+                from repro.cluster.deploy.local import (
+                    LocalLauncher,
+                    compile_cache_dir,
+                )
 
-                self.launcher = LocalLauncher(preload=self.preload)
+                self.launcher = LocalLauncher(
+                    preload=self.preload,
+                    compile_cache_dir=compile_cache_dir())
         node_ids = [f"node{i}" for i in range(self.nodes)]
         conn_wrapper = None
         if self.chaos is not None and self.chaos_controller is None:

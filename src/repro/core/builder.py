@@ -23,6 +23,7 @@ per requirement 7.
 
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Mapping, Sequence
@@ -34,16 +35,11 @@ from repro.core.channels import Channel, ShardingRules
 from repro.core.dsl import ClusterSpec
 from repro.core.timing import TimingCollector
 
-try:  # executable broadcast (JCSP code-loading channel analogue)
-    from jax.experimental.serialize_executable import (
-        deserialize_and_load as _deserialize_and_load,
-    )
-    from jax.experimental.serialize_executable import serialize as _serialize
-
-    _HAVE_SERIALIZE = True
-except Exception:  # pragma: no cover - older jax
-    _HAVE_SERIALIZE = False
-
+# Executable broadcast (JCSP code-loading channel analogue).
+from jax.experimental.serialize_executable import (
+    deserialize_and_load as _deserialize_and_load,
+)
+from jax.experimental.serialize_executable import serialize as _serialize
 
 LOAD_PORT = 2000  # paper §6: the load network uses port 2000 ...
 LOAD_CHANNEL = 1  # ... and channel number 1 on every node.
@@ -185,8 +181,6 @@ class StepArtifact:
     # -- executable broadcast (code-loading channel analogue) ----------------
 
     def serialize(self) -> bytes:
-        if not _HAVE_SERIALIZE:
-            raise RuntimeError("jax.experimental.serialize_executable unavailable")
         payload, _in_tree, _out_tree = _serialize(self.compiled)
         return payload
 
@@ -241,9 +235,8 @@ class ClusterBuilder:
         if out_shardings is not None:
             jit_kw["out_shardings"] = out_shardings
         jitted = jax.jit(fn, **jit_kw)
-        from repro.launch.mesh import use_mesh
-
-        with use_mesh(self.mesh):
+        with (contextlib.nullcontext() if self.mesh is None
+              else jax.set_mesh(self.mesh)):
             lowered = jitted.lower(*example_args)
             compiled = lowered.compile() if compile_now else None
         load_ms = (time.perf_counter() - t0) * 1e3
@@ -261,8 +254,6 @@ class ClusterBuilder:
     @staticmethod
     def load_serialized_step(payload: bytes, in_tree, out_tree) -> Any:
         """Node-side: load an executable broadcast by the host (§4.1)."""
-        if not _HAVE_SERIALIZE:
-            raise RuntimeError("jax.experimental.serialize_executable unavailable")
         return _deserialize_and_load(payload, in_tree, out_tree)
 
     # -- emit/cluster/collect application path -------------------------------

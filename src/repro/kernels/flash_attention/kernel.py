@@ -5,7 +5,8 @@ Tiling (FlashAttention re-thought for VMEM/MXU rather than SRAM/warps):
 * grid = (B*H, Sq / BLOCK_Q); each program owns one query block;
 * K/V live in VMEM as whole-sequence blocks (per (b,h) slice) — on v5e,
   Skv<=4096 bf16 keys+values = 2 x 1MiB, well under the ~16MiB VMEM budget;
-  the inner ``fori_loop`` walks KV in BLOCK_K chunks with ``pl.load``;
+  the inner ``fori_loop`` walks KV in BLOCK_K chunks by ref indexing
+  (``k_ref[pl.ds(...), :]``);
 * online softmax: running (max, denom, acc) in f32 registers, rescaled per
   chunk — no [Sq, Skv] tensor ever exists;
 * causal: the KV loop stops at the diagonal block (trip count is a
@@ -51,9 +52,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, causal: bool, window: int,
     def body(kb, state):
         m_prev, l_prev, acc = state
         k_start = kb * block_k
-        kv_idx = pl.dslice(k_start, block_k)
-        kk = pl.load(k_ref, (kv_idx, slice(None))).astype(jnp.float32)
-        vv = pl.load(v_ref, (kv_idx, slice(None))).astype(jnp.float32)
+        kv_idx = pl.ds(k_start, block_k)
+        kk = k_ref[kv_idx, :].astype(jnp.float32)
+        vv = v_ref[kv_idx, :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, kk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,

@@ -1,5 +1,6 @@
-"""Jitted public wrapper for the Mandelbrot kernel (auto-padding, backend
-dispatch: Pallas on TPU, interpret-mode Pallas or the jnp oracle on CPU)."""
+"""Jitted public wrapper for the Mandelbrot kernel: auto-padding to the
+kernel's tiles, and the caller's choice of the compiled Pallas kernel
+(default), interpret-mode Pallas, or the jnp oracle."""
 
 from __future__ import annotations
 
@@ -20,9 +21,13 @@ def mandelbrot(
     *,
     max_iters: int = 1000,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
-    """Escape-time iterations + colour for a coordinate grid [H, W]."""
+    """Escape-time iterations + colour for a coordinate grid [H, W].
+
+    The compiled kernel needs a TPU; on the CPU pass ``interpret=True``
+    (the Pallas interpreter) or ``use_pallas=False`` (the jnp oracle).
+    """
     if not use_pallas:
         return mandelbrot_reference(x0, y0, max_iters)
     H, W = x0.shape

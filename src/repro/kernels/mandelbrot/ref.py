@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def mandelbrot_reference(x0: jax.Array, y0: jax.Array, max_iters: int):
@@ -41,19 +42,21 @@ def mandelbrot_reference(x0: jax.Array, y0: jax.Array, max_iters: int):
     return iters, colour
 
 
-def line_coords(width: int, line_y: int, *, min_x=-2.5, min_y=1.0,
-                range_x=3.5):
-    """The paper's ``createInstance`` coordinate layout {4:26-39}."""
+def grid_coords(height: int, width: int, *, first_line: int = 0,
+                min_x=-2.5, min_y=1.0, range_x=3.5):
+    """The paper's ``createInstance`` coordinate layout {4:26-39} for lines
+    ``first_line .. first_line + height - 1``: two f32 [height, width] NumPy
+    grids.  Built on the host so that every band of a farm and the whole
+    grid of its oracle get bit-identical coordinates on any device."""
     delta = range_x / width
-    x = min_x + jnp.arange(width, dtype=jnp.float32) * delta
-    y = jnp.full((width,), min_y - line_y * delta, jnp.float32)
-    return x, y
+    x = np.float32(min_x) + np.arange(width, dtype=np.float32) * np.float32(delta)
+    lines = np.arange(first_line, first_line + height)
+    y = (min_y - lines * delta).astype(np.float32)
+    return (np.broadcast_to(x, (height, width)).copy(),
+            np.broadcast_to(y[:, None], (height, width)).copy())
 
 
-def grid_coords(height: int, width: int, **kw):
-    xs, ys = [], []
-    for r in range(height):
-        x, y = line_coords(width, r, **kw)
-        xs.append(x)
-        ys.append(y)
-    return jnp.stack(xs), jnp.stack(ys)
+def line_coords(width: int, line_y: int, **kw):
+    """One line of :func:`grid_coords`: two f32 [width] arrays."""
+    x, y = grid_coords(1, width, first_line=line_y, **kw)
+    return x[0], y[0]

@@ -34,10 +34,10 @@ def _rglru_kernel(a_ref, b_ref, h0_ref, h_ref, hlast_ref):
     S = a_ref.shape[0]
 
     def body(t, h):
-        a_t = pl.load(a_ref, (pl.dslice(t, 1), slice(None)))
-        b_t = pl.load(b_ref, (pl.dslice(t, 1), slice(None)))
+        a_t = a_ref[pl.ds(t, 1), :]
+        b_t = b_ref[pl.ds(t, 1), :]
         h = a_t.astype(jnp.float32) * h + b_t.astype(jnp.float32)
-        pl.store(h_ref, (pl.dslice(t, 1), slice(None)), h.astype(h_ref.dtype))
+        h_ref[pl.ds(t, 1), :] = h.astype(h_ref.dtype)
         return h
 
     h = h0_ref[...].astype(jnp.float32)

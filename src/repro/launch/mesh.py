@@ -12,44 +12,8 @@ all-reduce) on it.
 
 from __future__ import annotations
 
-import contextlib
-
 import jax
-
-try:  # jax >= 0.5: explicit/auto axis types on the mesh
-    from jax.sharding import AxisType
-
-    HAVE_AXIS_TYPES = True
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
-    HAVE_AXIS_TYPES = False
-
-
-def axis_types_kwargs(n_axes: int) -> dict:
-    """``axis_types=(AxisType.Auto,)*n`` where supported, ``{}`` otherwise.
-
-    On older jax (e.g. 0.4.x) every mesh axis is Auto already, so omitting
-    the kwarg preserves semantics exactly.
-    """
-    if HAVE_AXIS_TYPES:
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-    return {}
-
-
-def compat_make_mesh(shape, axis_names):
-    """``jax.make_mesh`` with Auto axis types on jax versions that have them."""
-    return jax.make_mesh(tuple(shape), tuple(axis_names),
-                         **axis_types_kwargs(len(axis_names)))
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh``: ``jax.set_mesh`` where it exists,
-    the ``Mesh`` context-manager protocol on older jax, no-op for ``None``."""
-    if mesh is None:
-        return contextlib.nullcontext()
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh  # jax<=0.4.x: Mesh is itself a context manager
+from jax.sharding import AxisType
 
 # TPU v5e hardware constants (roofline denominators).
 PEAK_FLOPS_BF16 = 197e12  # per chip
@@ -61,7 +25,7 @@ HBM_BYTES = 16 * 2**30  # per chip
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_smoke_mesh(data: int = 1, model: int = 1):
@@ -69,7 +33,8 @@ def make_smoke_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = min(model, max(n // data, 1))
-    return compat_make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def model_axis_size(mesh) -> int:

@@ -17,10 +17,9 @@ from dataclasses import dataclass
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.core.channels import ShardingRules, rules_for_shape_kind
-from repro.launch.mesh import axis_types_kwargs
 
 
 @dataclass
@@ -46,7 +45,8 @@ class ElasticController:
         )
         data = len(nodes) * self.devices_per_node
         mesh_devs = chosen.reshape(data, self.model_axis)
-        mesh = Mesh(mesh_devs, ("data", "model"), **axis_types_kwargs(2))
+        mesh = Mesh(mesh_devs, ("data", "model"),
+                    axis_types=(AxisType.Auto,) * 2)
         rules = rules_for_shape_kind(mesh, self.shape_kind)
         return mesh, rules
 

@@ -1,17 +1,6 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — tests must see 1 CPU device
 (only launch/dryrun + launch/roofline request 512 placeholder devices)."""
 
-import os
-import sys
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:  # deterministic fallback keeps the property tests running
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    import _hypothesis_fallback
-
-    _hypothesis_fallback.install(sys.modules)
-
 import jax
 import pytest
 

@@ -145,9 +145,50 @@ def test_membership_heartbeat_threshold_declares_death():
     assert m.finished()
 
 
+def test_membership_booting_node_misses_count_only_after_grace():
+    """A node that registered as booting is not reaped for silence until
+    it reports its boot done, or ``boot_grace_s`` after registering, or
+    its connection closes."""
+    m = Membership(HeartbeatMonitor(interval_s=0.1, misses=3),
+                   boot_grace_s=2.0)
+    m.register("node0", "127.0.0.1:1", booting=True, now=0.0)
+    m.register("node1", "127.0.0.1:2", booting=True, now=0.0)
+    m.register("node2", "127.0.0.1:3", booting=True, now=0.0)
+    m.end_boot("node2")  # its socket closed mid-boot
+    assert [r.node_id for r in m.reap(now=1.0)] == ["node2"]
+    m.beat("node1", now=1.5)  # boot done: plain deadline from here on
+    assert m.reap(now=1.7) == []
+    assert [r.node_id for r in m.reap(now=1.9)] == ["node1"]
+    assert m.nodes["node0"].alive  # still booting, within its grace
+    assert [r.node_id for r in m.reap(now=2.1)] == ["node0"]
+
+
 # ---------------------------------------------------------------------------
 # the real thing: subprocess clusters on localhost
 # ---------------------------------------------------------------------------
+
+
+def test_node_holding_interpreter_lock_at_boot_is_not_declared_dead():
+    """A node whose boot holds the interpreter lock (as a TPU client's
+    start does) sends no heartbeat meanwhile; the host must not count
+    that silence as death, and the node then serves jobs as usual."""
+    from repro.cluster.service import ClusterService
+
+    def work(x):
+        return x + 1
+
+    svc = ClusterService(nodes=1, workers=1, preload=("_boot_stall",),
+                         **FAST)
+    try:
+        with svc:
+            handle = svc.submit(_spec(1, 1, 8, work), timeout=60.0)
+            assert handle.result() == sum(range(1, 9))
+            assert svc.host_loader.stats.deaths_detected == 0
+            report = svc.metrics_snapshot()["nodes"]["node0"]["report"]
+            assert report["booting"] is False
+            assert report["boot_ms"] > 1000  # the stall was inside boot
+    finally:
+        assert svc.orphaned() == []
 
 
 def test_cluster_backend_bootstraps_and_completes():

@@ -25,6 +25,12 @@ from repro.cluster.deploy import (
     SSHLauncher,
 )
 from repro.cluster.deploy.base import NodeHandle
+from repro.cluster.deploy.local import (
+    DEFAULT_COMPILE_CACHE,
+    _child_env,
+    compile_cache_dir,
+    jax_node_env,
+)
 from repro.cluster.membership import (
     DONE,
     LAUNCHING,
@@ -502,3 +508,38 @@ def test_local_launcher_is_the_default_and_unchanged():
     assert isinstance(app.launcher, LocalLauncher)
     assert all(h.where == "local" for h in app.handles.values())
     assert app.orphaned() == []
+
+
+def test_jax_node_env_sets_no_platform(monkeypatch):
+    """A node's platform comes from whoever launches it: the overlay, the
+    local child env and the ssh exports add no JAX_PLATFORMS."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert jax_node_env() == {}
+    assert "JAX_PLATFORMS" not in jax_node_env("/cache")
+    assert "JAX_PLATFORMS" not in _child_env("/cache")
+    assert "JAX_PLATFORMS" not in SSHLauncher(["ws"])._remote_env()
+    # The caller's own platform still reaches a local child untouched.
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert _child_env()["JAX_PLATFORMS"] == "cpu"
+
+
+def test_env_compile_cache_dir_wins_over_compile_cache_dir(monkeypatch,
+                                                           tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/env/cache")
+    assert compile_cache_dir() == "/env/cache"
+    assert jax_node_env("/arg/cache")["JAX_COMPILATION_CACHE_DIR"] \
+        == "/env/cache"
+    assert _child_env("/arg/cache")["JAX_COMPILATION_CACHE_DIR"] \
+        == "/env/cache"
+    assert SSHLauncher(["ws"], compile_cache_dir="/arg/cache")._remote_env()[
+        "JAX_COMPILATION_CACHE_DIR"] == "/env/cache"
+    # Unset: the argument, and by default one fixed path in the checkout,
+    # whatever the working directory.
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    monkeypatch.chdir(tmp_path)
+    assert _child_env("/arg/cache")["JAX_COMPILATION_CACHE_DIR"] \
+        == "/arg/cache"
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache_dir() == DEFAULT_COMPILE_CACHE \
+        == os.path.join(root, "results", "xla_cache")

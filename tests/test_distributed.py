@@ -34,7 +34,7 @@ def test_smoke_arch_compiles_on_multi_device_mesh():
         from repro.configs.registry import get_config
         from repro.configs.base import ShapeConfig
         from repro.core.channels import training_rules
-        from repro.launch.mesh import compat_make_mesh, use_mesh
+        from jax.sharding import AxisType
         from repro.runtime import steps as steps_mod
         from repro.models.common import init_params, param_shardings
         from repro.optim import adamw
@@ -44,7 +44,8 @@ def test_smoke_arch_compiles_on_multi_device_mesh():
                                   d_model=64, num_heads=4, num_kv_heads=4,
                                   vocab_size=256, compute_dtype='float32')
         shape = ShapeConfig('t', seq_len=32, global_batch=8, kind='train')
-        mesh = compat_make_mesh((2, 4), ('data', 'model'))
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(AxisType.Auto,) * 2)
         rules = training_rules(mesh)
         opt_cfg = adamw.AdamWConfig()
         tp = 4
@@ -57,7 +58,7 @@ def test_smoke_arch_compiles_on_multi_device_mesh():
                                                  rules=rules))
         src = source_for(cfg, shape)
         batch = shard_batch(src.batch(0), rules)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             p1, o1, m1 = step(params, opt_state, batch, jnp.int32(0))
         print('sharded_loss', float(m1['loss']))
 
@@ -126,8 +127,9 @@ def test_executable_serialization_roundtrip():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core.builder import ClusterBuilder
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2, 2), ('data', 'model'))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 2), ('data', 'model'),
+                             axis_types=(AxisType.Auto,) * 2)
         x = jax.device_put(jnp.arange(16.0).reshape(4, 4),
                            NamedSharding(mesh, P('data', None)))
         builder = ClusterBuilder(mesh=mesh)

@@ -25,7 +25,7 @@ def keys(n):
 @pytest.mark.parametrize("h,w,iters", [(16, 128, 50), (32, 300, 100), (9, 77, 30)])
 def test_mandelbrot_matches_reference(h, w, iters):
     x0, y0 = grid_coords(h, w)
-    it_k, col_k = mandelbrot(x0, y0, max_iters=iters)
+    it_k, col_k = mandelbrot(x0, y0, max_iters=iters, interpret=True)
     it_r, col_r = mandelbrot_reference(x0, y0, iters)
     np.testing.assert_array_equal(np.asarray(it_k), np.asarray(it_r))
     np.testing.assert_array_equal(np.asarray(col_k), np.asarray(col_r))
@@ -35,7 +35,7 @@ def test_mandelbrot_paper_counts():
     """Paper section 8: on the full 3200x5600 grid ~14M of 17.92M points are
     white.  On a 1/8-scale grid the white fraction must be comparable."""
     x0, y0 = grid_coords(400, 700)
-    _iters, col = mandelbrot(x0, y0, max_iters=200)
+    _iters, col = mandelbrot(x0, y0, max_iters=200, interpret=True)
     white_frac = float(jnp.mean(col.astype(jnp.float32)))
     assert 0.70 < white_frac < 0.90  # paper: 14.06/17.92 = 0.785
 

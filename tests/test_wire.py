@@ -3,8 +3,7 @@
 Property tests over the zero-copy ndarray codec (codec 2): dtypes, 0-d,
 empty, non-contiguous and Fortran-order arrays, arrays nested inside
 msgpack payloads (ExtType), and the fallback ladder (object arrays and
-tuples -> pickle).  Runs under real hypothesis when installed, else under
-the deterministic fallback installed by conftest.py.
+tuples -> pickle), run under hypothesis.
 
 Also the regression for the deep-nesting guard: a payload too deep for any
 codec must raise a clear ValueError, not a RecursionError from inside a
@@ -73,8 +72,10 @@ def test_ndarray_roundtrip_fortran_and_noncontiguous(dtype, rows, cols):
     b = _roundtrip(fortran)
     assert np.array_equal(b, fortran) and b.shape == fortran.shape
 
-    sliced = base[::2, ::2]  # non-contiguous view: pays one compaction copy
-    assert not sliced.flags.c_contiguous
+    # A strided view pays one compaction copy; numpy counts a one-element
+    # view as contiguous, and hypothesis tries rows = cols = 1 first.
+    sliced = base[::2, ::2]
+    assert sliced.flags.c_contiguous == (sliced.size == 1)
     b = _roundtrip(sliced)
     assert np.array_equal(b, sliced) and b.dtype == sliced.dtype
 
