@@ -1,0 +1,6 @@
+"""device_idle_share (%): the share of the traced slice in which no
+operation ran on the chip (node's device trace)."""
+
+
+def read(run):
+    return None if run.trace is None else 100.0 * run.trace.idle_share
