@@ -39,14 +39,21 @@ def farm_spec(nclusters: int, workers: int, *, lines: int, width: int,
     def work(item: int):
         import jax.numpy as jnp  # the node imports its own (preloaded) jax
 
+        from repro.cluster.telemetry.spans import span
         from repro.kernels.mandelbrot.ops import mandelbrot
         from repro.kernels.mandelbrot.ref import grid_coords
 
-        x0, y0 = grid_coords(lines_per_item, width,
-                             first_line=item * lines_per_item)
-        iters, colour = mandelbrot(x0, y0, max_iters=max_iters,
-                                   interpret=interpret)
-        return (int(jnp.sum(iters)), int(jnp.sum(colour)), colour.size)
+        # The band program's three steps, as children of the node's
+        # ``node.work`` span: host coordinates, the jitted call (dispatch),
+        # and the two sums read back (which wait for the device).
+        with span("band.coords"):
+            x0, y0 = grid_coords(lines_per_item, width,
+                                 first_line=item * lines_per_item)
+        with span("band.call"):
+            iters, colour = mandelbrot(x0, y0, max_iters=max_iters,
+                                       interpret=interpret)
+        with span("band.readback"):
+            return (int(jnp.sum(iters)), int(jnp.sum(colour)), colour.size)
 
     def collect(acc, item):
         t, w, p = item

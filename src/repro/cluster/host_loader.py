@@ -89,7 +89,7 @@ from repro.cluster.membership import (
     NodeRecord,
 )
 from repro.cluster import peer as peer_mod
-from repro.cluster.telemetry import Telemetry
+from repro.cluster.telemetry import Telemetry, spans
 from repro.cluster.wire import (
     APP_WIRE_CHANNEL,
     CODE_CACHE_SLOTS,
@@ -213,6 +213,9 @@ class JobState:
         self.result: Any = None
         self.deadline: float | None = None
         self.submitted_at: float | None = None
+        # While spans are on: the submit's epoch ns, until the job's first
+        # WORK_BATCH closes its ``service.admit`` span.
+        self.admit_ns: int | None = None
         self.first_result_at: float | None = None
         self.ended_at: float | None = None
         # Failure attribution for the retry history: which node the fatal
@@ -411,9 +414,11 @@ class HostLoader:
         (see :class:`JobState`); the gateway sets them, direct service
         users normally leave the defaults.
         """
+        admit_ns = spans.stamp()
         job = self._new_job(spec, pinned=False, priority=priority,
                             timeout=timeout, tenant=tenant,
                             max_inflight=max_inflight)
+        job.admit_ns = admit_ns
         job.submitted_at = time.monotonic()
         self.telemetry.inc("jobs_submitted")
         self.telemetry.emit("job_submit", job=job.job_id,
@@ -630,10 +635,12 @@ class HostLoader:
                     self._answer(node_id, int(p.get("credits", 1)))
                 elif frame.ftype is FrameType.RESULT_BATCH:
                     p = frame.payload
-                    self._collect_results(
-                        node_id, frame.job_id, p["results"],
-                        int(p.get("credits", 0)),
-                    )
+                    with spans.span("host.collect", job=frame.job_id,
+                                    n=len(p["results"])):
+                        self._collect_results(
+                            node_id, frame.job_id, p["results"],
+                            int(p.get("credits", 0)),
+                        )
                 elif frame.ftype is FrameType.RESULT:
                     # Legacy single-result form (one frame per item).
                     self._collect_results(node_id, frame.job_id,
@@ -651,6 +658,9 @@ class HostLoader:
                         # Node-side phase/cache counters piggybacked on the
                         # beat (kept as the slow fallback channel).
                         self.telemetry.set_node(node_id, report=rep)
+                    compiles = (frame.payload or {}).get("compiles")
+                    if compiles:
+                        self.telemetry.set_node(node_id, compiles=compiles)
                 elif frame.ftype is FrameType.REPORT:
                     # Off-beat telemetry push: gauges track completions as
                     # they happen instead of lagging one heartbeat.  NOT a
@@ -748,12 +758,14 @@ class HostLoader:
     def _send_batch(self, rec: NodeRecord, job: JobState, batch: list,
                     s: int) -> bool:
         try:
-            rec.conn.send(Frame(
-                FrameType.WORK_BATCH,
-                {"items": [{"id": i, "obj": o, "s": s} for i, o in batch]},
-                APP_WIRE_CHANNEL,
-                job_id=job.job_id,
-            ))
+            with spans.span("host.dispatch", job=job.job_id, n=len(batch)):
+                rec.conn.send(Frame(
+                    FrameType.WORK_BATCH,
+                    {"items": [{"id": i, "obj": o, "s": s}
+                               for i, o in batch]},
+                    APP_WIRE_CHANNEL,
+                    job_id=job.job_id,
+                ))
         except OSError:
             # Never lose an item on a dead pipe: all of them go back to
             # the front of the queue; the node itself is reaped shortly.
@@ -766,6 +778,10 @@ class HostLoader:
             # forever, so they fail the job (one-shot run() re-raises).
             self._fail_job(job, exc)
             return False
+        if job.admit_ns is not None:
+            spans.record("service.admit", job.admit_ns, time.time_ns(),
+                         job=job.job_id)
+            job.admit_ns = None
         now = time.monotonic()
         for item_id, obj in batch:
             job.inflight[s][item_id] = (rec.node_id, obj)
@@ -1100,8 +1116,6 @@ class HostLoader:
         name = p.get("name")
         idx = int(p.get("chunk", 0))
         data = self.blocks.get_chunk(name, idx)
-        if data is not None:
-            self.telemetry.observe("block_chunk_bytes", len(data))
         try:
             rec.conn.send(Frame(
                 FrameType.BLOCK_CHUNK,

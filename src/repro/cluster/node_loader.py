@@ -69,6 +69,7 @@ from typing import Any, Callable, Sequence
 
 from repro.cluster import peer as peer_mod
 from repro.cluster.netchannels import ChannelClosed
+from repro.cluster.telemetry import spans
 from repro.cluster.wire import (
     APP_WIRE_CHANNEL,
     CODE_CACHE_SLOTS,
@@ -93,6 +94,13 @@ REPORT_MIN_INTERVAL_S = 0.05
 # blocks, the kernel buffers fill, and TCP throttles the upstream sender
 # instead of this node's queue growing without bound.
 PEER_INTAKE_MAX_ITEMS = 256
+
+# The JAX compile events the node counts once a preload has brought JAX
+# in, and sends with each heartbeat: counter -> monitoring event.
+COMPILE_EVENTS = {
+    "jaxpr_traces": "/jax/core/compile/jaxpr_trace_duration",
+    "backend_compiles": "/jax/core/compile/backend_compile_duration",
+}
 
 # AOT-serialized executables shipped in the LOAD payload, keyed by name.
 # Work functions may read these (e.g. deserialize_and_load a compiled step).
@@ -142,6 +150,24 @@ def connect_with_retry(host: str, port: int, timeout: float = 30.0, *,
             delay = min(delay * 2, max_delay)
 
 
+def count_compiles(jax, counts: dict) -> None:
+    """Count, in ``counts``, each jaxpr trace and backend compile this
+    process makes from now on, and their milliseconds (``<key>_ms``): a
+    step that recompiles inside a timed window shows as a count that
+    moved."""
+    keys = {event: key for key, event in COMPILE_EVENTS.items()}
+    lock = threading.Lock()
+
+    def listener(event: str, seconds: float, **_kw) -> None:
+        key = keys.get(event)
+        if key is not None:
+            with lock:
+                counts[key] += 1
+                counts[f"{key}_ms"] += seconds * 1e3
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+
+
 def run_node(
     host: str,
     port: int,
@@ -168,6 +194,11 @@ def run_node(
     report = {"booting": bool(preload), "boot_ms": 0.0, "backend_ms": None,
               "load_ms": 0.0, "items": 0, "cache_hits": 0,
               "cache_misses": 0, "jobs_bound": 0}
+    # Slow-moving, so they ride the heartbeat alone and not every REPORT.
+    compiles = {}
+    for key in COMPILE_EVENTS:
+        compiles[key] = 0
+        compiles[f"{key}_ms"] = 0.0
 
     # Heavy dependencies import concurrently with registration: the cost of
     # booting the environment lands in boot_ms, not in the code-distribution
@@ -183,6 +214,7 @@ def run_node(
                 pass
         jax = sys.modules.get("jax")
         if jax is not None:
+            count_compiles(jax, compiles)
             t0 = time.perf_counter()
             try:
                 jax.devices()
@@ -236,7 +268,8 @@ def run_node(
     def beat() -> None:
         conn.send(Frame(
             FrameType.HEARTBEAT,
-            {"node_id": node_id, "report": snapshot_report()},
+            {"node_id": node_id, "report": snapshot_report(),
+             "compiles": dict(compiles)},
             LOAD_WIRE_CHANNEL,
         ))
 
@@ -294,6 +327,9 @@ def run_node(
     items_lock = threading.Lock()
     out_lock = threading.Lock()
     out_bufs: dict[int, list[dict]] = {}  # job_id -> pending results
+    # While spans are on: (job_id, item id, buffered at) per pending result,
+    # for its ``node.hold`` span.
+    out_held: list[tuple[int, Any, int]] = []
     # Per-job flush cadence: a pool job whose stages carry ``flush_ms=``
     # tightens the flusher's wake interval while it is bound (min over its
     # stages' values; the node-global flush_interval is the ceiling).
@@ -350,7 +386,7 @@ def run_node(
             for item in items:
                 s = int(item.get("s", 0))
                 if (job_id, s) in fns:
-                    work_q.put((job_id, item))
+                    work_q.put((job_id, item, None))
                 else:
                     peer_hold.setdefault(job_id, []).append(item)
 
@@ -358,8 +394,11 @@ def run_node(
     peer_server.set_intake_gate(peer_intake_gate)
 
     def complete(job_id: int, result: dict, urgent: bool = False) -> None:
+        held = spans.stamp()
         with out_lock:
             out_bufs.setdefault(job_id, []).append(result)
+            if held is not None:
+                out_held.append((job_id, result["id"], held))
             n = sum(len(b) for b in out_bufs.values())
         if urgent or n >= flush_items:
             flush_now.set()
@@ -380,6 +419,8 @@ def run_node(
         with out_lock:
             batches = [(jid, buf) for jid, buf in out_bufs.items() if buf]
             out_bufs.clear()
+            held = out_held[:]
+            out_held.clear()
         sent_any = False
         for jid, batch in batches:
             rt = route_tables.get(jid)
@@ -448,8 +489,9 @@ def run_node(
                        "credits": sum(1 for r in host_results
                                       if not r.get("peer"))}
             try:
-                conn.send(Frame(FrameType.RESULT_BATCH, payload,
-                                APP_WIRE_CHANNEL, job_id=jid))
+                with spans.span("node.flush", job=jid, n=len(host_results)):
+                    conn.send(Frame(FrameType.RESULT_BATCH, payload,
+                                    APP_WIRE_CHANNEL, job_id=jid))
                 sent_any = True
             except OSError:
                 pass  # host gone: the nrfa loop shuts the node down
@@ -471,6 +513,10 @@ def run_node(
                     sent_any = True
                 except OSError:
                     pass
+        if held:
+            sent = time.time_ns()
+            for jid, item_id, t in held:
+                spans.record("node.hold", t, sent, job=jid, item=item_id)
         if sent_any:
             send_report()
 
@@ -490,13 +536,17 @@ def run_node(
             flush()
         flush()  # drain the tail after the workers joined
 
-    def worker() -> None:
+    def worker(index: int) -> None:
         nonlocal items_done
         while True:
-            got = work_q.get()
+            with spans.span("node.idle", worker=index):
+                got = work_q.get()
             if got is UT:
                 return
-            job_id, item = got
+            job_id, item, queued = got
+            if queued is not None:
+                spans.record("node.queue", queued, time.time_ns(),
+                             job=job_id, item=item["id"])
             s = int(item.get("s", 0))
             # Results remember whether their input arrived from a peer: the
             # flusher returns window credits only for host-dispatched items.
@@ -516,7 +566,8 @@ def run_node(
                          urgent=True)
                 continue
             try:
-                value = fn(item["obj"])
+                with spans.span("node.work", job=job_id, item=item["id"]):
+                    value = fn(item["obj"])
                 if slowdown > 0.0:
                     time.sleep(slowdown)  # injected straggler (§6.1 testing)
                 complete(job_id, {"id": item["id"], "s": s, "value": value,
@@ -574,7 +625,7 @@ def run_node(
                 held = peer_hold.pop(job_id, [])
                 for item in held:
                     if (job_id, int(item.get("s", 0))) in fns:
-                        work_q.put((job_id, item))
+                        work_q.put((job_id, item, None))
                     else:
                         peer_hold.setdefault(job_id, []).append(item)
 
@@ -655,8 +706,8 @@ def run_node(
             ARTIFACTS.update(plan.get("artifacts") or {})
             bind_stages(job_id, plan)
             for i in range(workers):
-                t = threading.Thread(target=worker, name=f"nl-worker{i}",
-                                     daemon=True)
+                t = threading.Thread(target=worker, args=(i,),
+                                     name=f"nl-worker{i}", daemon=True)
                 t.start()
                 worker_threads.append(t)
             flush_thread.start()
@@ -707,10 +758,11 @@ def run_node(
                 load_ms += (time.perf_counter() - t0) * 1e3
                 report["load_ms"] = round(load_ms, 3)
             elif frame.ftype is FrameType.WORK_BATCH:
+                queued = spans.stamp()  # each item's ``node.queue`` start
                 for item in frame.payload["items"]:
-                    work_q.put((frame.job_id, item))
+                    work_q.put((frame.job_id, item, queued))
             elif frame.ftype is FrameType.WORK:  # legacy single form
-                work_q.put((frame.job_id, frame.payload))
+                work_q.put((frame.job_id, frame.payload, spans.stamp()))
             elif frame.ftype is FrameType.BLOCK_CHUNK:
                 # A host reply to one of our striped BLOCK_REQUESTs.
                 p = frame.payload or {}

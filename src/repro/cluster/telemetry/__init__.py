@@ -1,11 +1,14 @@
 """repro.cluster.telemetry — live observability for the cluster.
 
-Three pieces, all stdlib-only (this package must import without jax, like
+Four pieces, all stdlib-only (this package must import without jax, like
 the rest of the node-loader bootstrap path):
 
 * :mod:`~repro.cluster.telemetry.registry` — the thread-safe event bus +
   metrics registry every host-side component publishes into, plus the
   JSONL trace writer for offline replay;
+* :mod:`~repro.cluster.telemetry.spans` — per-process span recording
+  (off by default), from a job's submit to the node's band program, on
+  the epoch clock that the JAX profiler's trace shares;
 * :mod:`~repro.cluster.telemetry.http` — the ``GET /metrics`` / ``/jobs``
   / ``/nodes`` / ``/events`` status endpoint (JSON + Prometheus text);
 * :mod:`~repro.cluster.telemetry.dashboard` — the self-contained HTML

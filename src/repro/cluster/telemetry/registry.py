@@ -42,14 +42,12 @@ EVENT_RING_SLOTS = 1024
 # producer observes into the same boundaries (upper bounds, inclusive —
 # Prometheus ``le`` semantics; an implicit +Inf bucket closes each grid).
 HISTOGRAM_BUCKETS: dict[str, tuple[float, ...]] = {
-    # Dispatch-to-completion per item (WORK_BATCH send to result/ack).
-    "item_latency_ms": (1, 2.5, 5, 10, 25, 50, 100, 250, 500,
-                        1000, 2500, 5000, 10000),
+    # Dispatch-to-completion per item (WORK_BATCH send to result/ack):
+    # doublings from a quarter of a millisecond, so that a 1.3 ms item
+    # falls between two buckets a factor of two apart, up to 8.192 s.
+    "item_latency_ms": tuple(0.25 * 2 ** k for k in range(16)),
     # Items per RESULT_BATCH frame (how well the flusher coalesces).
     "result_batch_items": (1, 2, 4, 8, 16, 32, 64, 128, 256),
-    # Broadcast-block chunk sizes served (host or peer side).
-    "block_chunk_bytes": (1 << 12, 1 << 14, 1 << 16, 1 << 18,
-                          1 << 20, 1 << 22, 1 << 24),
 }
 _DEFAULT_BUCKETS = (0.1, 1.0, 10.0, 100.0, 1000.0, 10000.0)
 
@@ -369,7 +367,7 @@ class Telemetry:
                     sample(f"repro_job_{key}", {"job": jid}, val)
         for nid, fields in snap["nodes"].items():
             flat = dict(fields)
-            for nest in ("wire", "report"):
+            for nest in ("wire", "report", "compiles"):
                 for key, val in (flat.pop(nest, None) or {}).items():
                     flat[f"{nest}_{key}"] = val
             state = flat.pop("state", None)

@@ -5,6 +5,7 @@ kernel in the Pallas interpreter, checked against the jnp oracle."""
 import importlib.util
 import json
 import pathlib
+import time
 
 import numpy as np
 import pytest
@@ -84,3 +85,115 @@ def test_band_coords_tile_the_whole_grid():
     np.testing.assert_array_equal(lx, x[7])
     np.testing.assert_array_equal(ly, y[7])
     assert x.dtype == y.dtype == np.float32
+
+
+def _one_item(work):
+    """A job of one item whose work function is ``work``: how spans are
+    turned on and collected in a node-loader."""
+    from repro.core.dsl import ClusterSpec
+    from repro.core.processes import EmitDetails, ResultDetails
+
+    return ClusterSpec.simple(
+        host="127.0.0.1", nclusters=1, workers_per_node=1,
+        emit_details=EmitDetails(
+            name="one", init=lambda: False,
+            create=lambda sent: (None, sent) if sent else (0, True)),
+        work_function=work,
+        result_details=ResultDetails(name="one", init=lambda: None,
+                                     collect=lambda _acc, r: r))
+
+
+def test_farm_spans_cover_every_band_and_add_no_wire_bytes(monkeypatch):
+    """A real one-node farm on the CPU, its spans off and then on in host
+    and node: with spans on, every band has one ``node.queue``,
+    ``node.work`` and ``node.hold`` span with its (job, item), the band
+    program's spans inherit those ids, and the bands' data frames carry
+    the same bytes as with spans off (no field rides the wire for them).
+    One worker, no prefetch and a flush per result make each band one
+    WORK_BATCH and one RESULT_BATCH of one item, so bytes per band repeat
+    exactly."""
+    from repro.apps.mandelbrot_farm import farm_spec
+    from repro.cluster.service import ClusterService
+    from repro.cluster.telemetry import spans
+    from repro.cluster.wire import FrameConnection, FrameType
+
+    data_bytes: dict[int, int] = {}
+    send, recv = FrameConnection.send, FrameConnection.recv
+
+    def counted_send(self, frame):
+        before = self.counters.bytes_sent
+        send(self, frame)
+        if frame.ftype is FrameType.WORK_BATCH:
+            data_bytes[frame.job_id] = (data_bytes.get(frame.job_id, 0)
+                                        + self.counters.bytes_sent - before)
+
+    def counted_recv(self):
+        before = self.counters.bytes_recv
+        frame = recv(self)
+        if frame.ftype is FrameType.RESULT_BATCH:
+            data_bytes[frame.job_id] = (data_bytes.get(frame.job_id, 0)
+                                        + self.counters.bytes_recv - before)
+        return frame
+
+    monkeypatch.setattr(FrameConnection, "send", counted_send)
+    monkeypatch.setattr(FrameConnection, "recv", counted_recv)
+    lines, bands = 32, 32 // LINES_PER_ITEM
+    spec = farm_spec(1, 1, lines=lines, width=256, max_iters=32,
+                     interpret=True)
+    svc = ClusterService(nodes=1, workers=1, prefetch=0, flush_items=1,
+                         preload=("repro.kernels.mandelbrot.ops",))
+    try:
+        with svc:
+            off = svc.submit(spec, timeout=120)
+            answer = off.result()
+            time.sleep(1.2)  # a heartbeat carries the node's counts
+            compiles = {k: v for k, v in svc.metrics_snapshot()["nodes"]
+                        ["node0"]["compiles"].items()
+                        if k in ("jaxpr_traces", "backend_compiles")}
+            svc.submit(_one_item(spans.enable), timeout=60).result()
+            spans.enable()
+            on = svc.submit(spec, timeout=120)
+            assert on.result() == answer
+            svc.submit(_one_item(spans.disable), timeout=60).result()
+            node_spans, node_dropped = svc.submit(
+                _one_item(spans.drain), timeout=60).result()
+            # Off only now: the job's last host.collect closes after its
+            # result is released to the caller.
+            spans.disable()
+            time.sleep(1.2)
+            counts = svc.metrics_snapshot()["nodes"]["node0"]["compiles"]
+    finally:
+        spans.disable()
+    host_spans, host_dropped = spans.drain()
+    assert (node_dropped, host_dropped) == (0, 0)
+    # The kernel compiled in the first job, and not again in the second.
+    assert compiles["jaxpr_traces"] >= 1 and compiles["backend_compiles"] >= 1
+    assert {k: counts[k] for k in compiles} == compiles
+    assert counts["backend_compiles_ms"] > 0
+
+    assert data_bytes[off.job_id] == data_bytes[on.job_id]
+    assert data_bytes[on.job_id] > 0
+
+    job = on.job_id
+    per_band = {}
+    for name, start, end, span_id, parent, thread, ids in node_spans:
+        assert end >= start
+        if ids.get("job") == job and "item" in ids:
+            per_band.setdefault(name, []).append(ids["item"])
+    for name in ("node.queue", "node.work", "node.hold", "band.coords",
+                 "band.call", "band.readback"):
+        assert sorted(per_band[name]) == list(range(bands)), name
+    work = {r[3]: r for r in node_spans if r[0] == "node.work"}
+    for r in node_spans:
+        if r[0].startswith("band."):
+            parent = work[r[4]]  # each is a child of its band's node.work
+            assert parent[6] == r[6]
+            assert parent[1] <= r[1] <= r[2] <= parent[2]
+    assert any(r[0] == "node.idle" and r[6] == {"worker": 0}
+               for r in node_spans)
+    assert any(r[0] == "node.flush" and r[6]["job"] == job
+               for r in node_spans)
+    names = [r[0] for r in host_spans if r[6].get("job") == job]
+    assert names.count("service.admit") == 1
+    assert names.count("host.dispatch") == bands
+    assert names.count("host.collect") == bands

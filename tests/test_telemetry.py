@@ -9,6 +9,7 @@ layer.  Everything stays on 127.0.0.1 with stdlib HTTP only.
 """
 
 import json
+import sys
 import threading
 import time
 import urllib.error
@@ -21,6 +22,7 @@ from repro.cluster.membership import Membership
 from repro.cluster.service import ClusterService
 from repro.cluster.telemetry import (
     Telemetry,
+    spans,
     TelemetryServer,
     TraceWriter,
     read_trace,
@@ -225,6 +227,131 @@ def test_histogram_unknown_family_gets_default_grid():
     t.observe("made_up_metric", 0.05)
     h = t.snapshot()["histograms"]["made_up_metric"]
     assert h["buckets"][0] == [0.1, 1]  # default grid starts at 0.1
+
+
+def test_item_latency_grid_resolves_a_band():
+    t = Telemetry(clock=lambda: 0.0)
+    t.observe("item_latency_ms", 1.3)  # one Mandelbrot band
+    t.observe("item_latency_ms", 0.2)
+    h = t.snapshot()["histograms"]["item_latency_ms"]
+    bounds = [le for le, _ in h["buckets"]]
+    assert bounds[:4] == [0.25, 0.5, 1.0, 2.0] and bounds[-1] == 8192.0
+    assert dict(h["buckets"])[1.0] == 1 and dict(h["buckets"])[2.0] == 2
+
+
+# ---------------------------------------------------------------------------
+# spans
+# ---------------------------------------------------------------------------
+
+
+def _span_off_records_nothing(rec):
+    with rec.span("a", job=1):
+        with rec.span("b"):
+            pass
+    rec.record("c", 1, 2, job=1)
+    assert rec.stamp() is None
+    assert rec.span("a") is rec.span("b")  # one shared no-op
+    assert rec.drain() == ([], 0)
+
+
+def _span_nesting_and_self_time(rec):
+    rec.enable()
+    with rec.span("outer") as outer:
+        time.sleep(0.002)
+        with rec.span("inner") as inner:
+            time.sleep(0.003)
+    records, dropped = rec.drain()
+    assert dropped == 0
+    by = {r[0]: r for r in records}
+    assert by["inner"][4] == outer.span_id == by["outer"][3]
+    assert by["outer"][4] is None and by["inner"][3] == inner.span_id
+    assert by["outer"][1] <= by["inner"][1] <= by["inner"][2] \
+        <= by["outer"][2]
+    table = spans.self_times(records)
+    o_dur = by["outer"][2] - by["outer"][1]
+    i_dur = by["inner"][2] - by["inner"][1]
+    assert table["inner"] == (1, i_dur, i_dur)
+    assert table["outer"] == (1, o_dur, o_dur - i_dur)
+    # Two overlapping children cover their union, not their sum.
+    table = spans.self_times([("p", 0, 100, 1, None, "t", {}),
+                              ("c", 10, 50, 2, 1, "t", {}),
+                              ("c", 30, 70, 3, 1, "t", {}),
+                              ("c", 90, 120, 4, 1, "t", {})])
+    assert table["p"] == (1, 100, 100 - 60 - 10)
+
+
+def _span_ids_are_inherited(rec):
+    rec.enable()
+
+    def band():  # takes no ids: they come from the span open around it
+        with rec.span("band.call"):
+            pass
+
+    with rec.span("node.work", job=7, item=3):
+        band()
+    with rec.span("node.work", job=7, item=4):
+        with rec.span("band.call", item=99, extra=1):
+            pass
+    records, _ = rec.drain()
+    calls = [r[6] for r in records if r[0] == "band.call"]
+    assert calls == [{"job": 7, "item": 3}, {"job": 7, "item": 99, "extra": 1}]
+    assert all(r[5] == threading.current_thread().name for r in records)
+
+
+def _span_full_ring_counts_drops(rec):
+    rec.enable()
+    for i in range(10):
+        with rec.span("s", i=i):
+            pass
+    records, dropped = rec.drain()
+    assert dropped == 6
+    assert [r[6]["i"] for r in records] == [6, 7, 8, 9]  # the newest kept
+    assert rec.drain() == ([], 0)
+
+
+def _span_threads_lose_nothing(rec):
+    rec.enable()
+    per_thread = 2000
+
+    def run(k):
+        for i in range(per_thread):
+            with rec.span("outer", worker=k):
+                with rec.span("inner", i=i):
+                    pass
+            if i % 2:
+                rec.record("cross", 1, 2, worker=k)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=run, args=(k,)) for k in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    records, dropped = rec.drain()
+    assert dropped == 0
+    assert len(records) == 4 * per_thread * 2 + 4 * per_thread // 2
+    assert len({r[3] for r in records}) == len(records)  # unique ids
+    outer = {r[3]: r[6]["worker"] for r in records if r[0] == "outer"}
+    for r in records:
+        if r[0] == "inner":  # each parent is its own thread's outer span
+            assert outer[r[4]] == r[6]["worker"]
+
+
+@pytest.mark.parametrize("case", [
+    _span_off_records_nothing,
+    _span_nesting_and_self_time,
+    _span_ids_are_inherited,
+    _span_full_ring_counts_drops,
+    _span_threads_lose_nothing,
+], ids=lambda f: f.__name__[len("_span_"):])
+def test_spans(case):
+    slots = 4 if case is _span_full_ring_counts_drops else 1 << 16
+    case(spans.Recorder(slots=slots))
 
 
 def test_trace_jsonl_round_trip(tmp_path):
