@@ -56,13 +56,18 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.apps.mandelbrot_farm import LINES_PER_ITEM, farm_spec, warm_spec
+from repro.apps.mandelbrot_farm import (
+    LINES_PER_ITEM,
+    device_x_row,
+    farm_spec,
+    warm_spec,
+)
 from repro.cluster.deploy.local import compile_cache_dir
 from repro.core.builder import ClusterBuilder
 from repro.core.dsl import ClusterSpec, Pipeline
 from repro.core.processes import EmitDetails, ResultDetails
-from repro.kernels.mandelbrot.ops import mandelbrot
-from repro.kernels.mandelbrot.ref import grid_coords
+from repro.kernels.mandelbrot.ops import band_totals, mandelbrot
+from repro.kernels.mandelbrot.ref import grid_coords, line_ys
 
 # Scaled-down Mandelbrot instance (paper: 3200 lines x 5600 points, esc 1000).
 LINES = int(os.environ.get("REPRO_BENCH_LINES", "120"))
@@ -139,21 +144,33 @@ def _run_spec(nclusters: int, workers: int, backend: str = "threads", *,
 
 
 def _warm(max_iters: int = MAX_ITERS) -> None:
-    # compile the kernel once so host-side (threads) rows measure compute,
-    # not tracing; called only once this section's nodes have exited
+    # compile the band's programs once so host-side (threads) rows measure
+    # compute, not tracing: the farm's band program and the kernel at the
+    # band shape (the pipeline's render); called only once this section's
+    # nodes have exited
     x0, y0 = grid_coords(LINES_PER_ITEM, WIDTH)
     jax.block_until_ready(mandelbrot(x0, y0, max_iters=max_iters,
                                      interpret=INTERPRET))
+    y_col = line_ys(LINES_PER_ITEM, WIDTH)[:, None]
+    jax.block_until_ready(band_totals(device_x_row(WIDTH), y_col,
+                                      max_iters=max_iters,
+                                      interpret=INTERPRET))
 
 
-def _warm_nodes(max_iters: int) -> None:
-    """Compile the kernel on one node-loader, untimed, before a section's
-    cluster rows: their nodes then load it from the shared persistent
-    cache instead of compiling inside the timed window, as the threads
-    rows run after ``_warm``.  The node exits before the rows start."""
+def _one_band_farm(max_iters: int):
+    """A farm of one band: what ``_warm_nodes`` runs before farm rows."""
+    return farm_spec(1, 1, lines=LINES_PER_ITEM, width=WIDTH,
+                     max_iters=max_iters, interpret=INTERPRET)
+
+
+def _warm_nodes(spec) -> None:
+    """Compile ``spec``'s device program on one node-loader, untimed,
+    before a section's cluster rows: their nodes then load it from the
+    shared persistent cache instead of compiling inside the timed window,
+    as the threads rows run after ``_warm``.  The node exits before the
+    rows start."""
     ClusterBuilder().build_application(
-        warm_spec(width=WIDTH, max_iters=max_iters, interpret=INTERPRET),
-        backend="cluster", **_cluster_kw()).run()
+        spec, backend="cluster", **_cluster_kw()).run()
 
 
 def _enable_compile_cache() -> None:
@@ -226,7 +243,7 @@ def table4_threads_vs_processes() -> list[str]:
         if backend == "threads":
             _warm(T4_MAX_ITERS)
         else:
-            _warm_nodes(T4_MAX_ITERS)
+            _warm_nodes(_one_band_farm(T4_MAX_ITERS))
         dt, result, timing, app = _run_spec(2, 2, backend=backend, **size_kw)
         results[backend] = result
         items = {t.node_id: t.items for t in timing.nodes
@@ -328,7 +345,7 @@ def warm_resubmit() -> list[str]:
     from repro.cluster.service import ClusterService
 
     size_kw = dict(lines=T4_LINES, max_iters=T4_MAX_ITERS)
-    _warm_nodes(T4_MAX_ITERS)
+    _warm_nodes(_one_band_farm(T4_MAX_ITERS))
     # One spec object resubmitted as-is: identical function objects pickle
     # to identical bytes, which is what makes the digest cache hit.
     spec = farm_spec(2, 2, width=WIDTH, interpret=INTERPRET, **size_kw)
@@ -733,7 +750,7 @@ def chaos_smoke() -> list[str]:
 
     size_kw = dict(lines=T4_LINES, max_iters=T4_MAX_ITERS)
     spec = farm_spec(4, 1, width=WIDTH, interpret=INTERPRET, **size_kw)
-    _warm_nodes(T4_MAX_ITERS)
+    _warm_nodes(_one_band_farm(T4_MAX_ITERS))
 
     launcher = _bench_launcher()
     if launcher is None:
@@ -888,7 +905,8 @@ def pipeline_two_stage() -> list[str]:
     every forwarded band) and whether the backends agree.
     """
     _enable_compile_cache()
-    _warm_nodes(P2_MAX_ITERS)
+    _warm_nodes(warm_spec(width=WIDTH, max_iters=P2_MAX_ITERS,
+                           interpret=INTERPRET))
     runs = {}
     for backend in ("cluster", "threads"):  # nodes first: see module doc
         builder = ClusterBuilder()
@@ -942,7 +960,8 @@ def peer_pipeline() -> list[str]:
     the peer run, and at least one chunk fetched from a peer.
     """
     _enable_compile_cache()
-    _warm_nodes(P2_MAX_ITERS)
+    _warm_nodes(warm_spec(width=WIDTH, max_iters=P2_MAX_ITERS,
+                           interpret=INTERPRET))
     from repro.cluster.service import ClusterService
 
     launcher = _bench_launcher()

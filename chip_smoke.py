@@ -7,13 +7,14 @@ launcher and liveness settings, of one node-loader process with four
 workers.  That node-loader is the one process that owns the chip: it
 starts the TPU backend while it boots (its preload imports jax).  It
 first takes a warm-up job, which reports its device and renders the
-middle band with the Pallas kernel there (the kernel's compile), then the
-farm twice: cold (the farm's code is shipped) and warm (the node rebinds
-it from its code cache).  Once the pool has closed and its node process
-has exited, this process takes the chip and checks, against the jnp
-oracle: both submissions' totals, exactly; the warm-up's band point by
-point (the farm's own band program); and the compiled kernel over the
-whole grid point by point (a second program, the grid in one call).
+middle band with the Pallas kernel there (the kernel's compile), then
+the farm twice: cold (the farm's code is shipped and its band program
+compiles) and warm (the node rebinds it from its code cache).  Once the
+pool has closed and its node process has exited, this process takes the
+chip and checks, against the jnp oracle: both submissions' totals,
+exactly; the warm-up's band point by point (the kernel at the farm's
+band shape); and the compiled kernel over the whole grid point by point
+(a second program, the grid in one call).
 
 Earlier lines of standard output carry the figures; the last line is one
 JSON object, ``{"ok": true, "device": {"platform": "tpu", "kind": ...,
@@ -148,8 +149,8 @@ def run_farm(*, lines: int, width: int, max_iters: int, interpret: bool,
                         "seconds": time.perf_counter() - t0}
     log(f"oracle: {report['oracle']}")
 
-    # The warm-up's band, rendered on the node by the farm's band program,
-    # point by point.
+    # The warm-up's band, rendered on the node by the kernel at the farm's
+    # band shape, point by point.
     band = oracle_iters[band_line:band_line + LINES_PER_ITEM]
     report["band_points_differing"] = int(
         np.sum(device["band_iters"] != np.asarray(band)))

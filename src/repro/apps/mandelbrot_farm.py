@@ -1,17 +1,26 @@
 """The paper's Mandelbrot farm (§8) as an emit → cluster → collect spec.
 
 Emit hands out band numbers; a worker renders one band of
-``LINES_PER_ITEM`` lines with :func:`repro.kernels.mandelbrot.ops.mandelbrot`
-and returns ``(iterations, white, points)``; collect sums the triples in
-Python ints.  The paper's instance is 3200 lines x 5600 points with escape
-value 1000: 800 bands and 17,920,000 points, whose iteration sum can reach
-1.79e10 and so never fits an int32.
+``LINES_PER_ITEM`` lines and returns ``(iterations, white, points)``;
+collect sums the triples in Python ints.  The paper's instance is 3200
+lines x 5600 points with escape value 1000: 800 bands and 17,920,000
+points, whose iteration sum can reach 1.79e10 and so never fits an int32.
+
+A band is one device program,
+:func:`repro.kernels.mandelbrot.ops.band_totals`: the line's x values stay
+on the node's device (:func:`device_x_row`, put there once per width), the
+band's ``LINES_PER_ITEM`` y values go up (16 B), the Pallas kernel renders
+the band and both sums come back in one int32[2].  Its shapes depend on
+the band alone, so the first job of a width compiles what every later job
+of that width runs, whatever its number of lines.
 
 Whether the kernel runs compiled or in the Pallas interpreter is the
 caller's choice (``interpret=``); nothing here looks at the backend.
 """
 
 from __future__ import annotations
+
+import threading
 
 from repro.core.dsl import ClusterSpec
 from repro.core.processes import EmitDetails, ResultDetails
@@ -37,23 +46,28 @@ def farm_spec(nclusters: int, workers: int, *, lines: int, width: int,
         return i, (i + 1, n)
 
     def work(item: int):
-        import jax.numpy as jnp  # the node imports its own (preloaded) jax
+        import numpy as np
 
+        # Imported on the node, not captured: this closure ships by value
+        # with every job, and the node's module keeps the row cache.
+        from repro.apps.mandelbrot_farm import device_x_row
         from repro.cluster.telemetry.spans import span
-        from repro.kernels.mandelbrot.ops import mandelbrot
-        from repro.kernels.mandelbrot.ref import grid_coords
+        from repro.kernels.mandelbrot.ops import band_totals
+        from repro.kernels.mandelbrot.ref import line_ys
 
         # The band program's three steps, as children of the node's
-        # ``node.work`` span: host coordinates, the jitted call (dispatch),
-        # and the two sums read back (which wait for the device).
+        # ``node.work`` span: the band's y values (16 B for 4 lines), the
+        # one device call (dispatch), and its two sums read back at once
+        # (which waits for the device).
         with span("band.coords"):
-            x0, y0 = grid_coords(lines_per_item, width,
-                                 first_line=item * lines_per_item)
+            y_col = line_ys(lines_per_item, width,
+                            first_line=item * lines_per_item)[:, None]
         with span("band.call"):
-            iters, colour = mandelbrot(x0, y0, max_iters=max_iters,
-                                       interpret=interpret)
+            totals = band_totals(device_x_row(width), y_col,
+                                 max_iters=max_iters, interpret=interpret)
         with span("band.readback"):
-            return (int(jnp.sum(iters)), int(jnp.sum(colour)), colour.size)
+            iters, white = np.asarray(totals).tolist()
+            return (iters, white, lines_per_item * width)
 
     def collect(acc, item):
         t, w, p = item
@@ -74,15 +88,42 @@ def farm_spec(nclusters: int, workers: int, *, lines: int, width: int,
     )
 
 
+_X_ROWS: dict = {}  # width -> f32 [1, width] on this process's device
+_X_ROWS_LOCK = threading.Lock()
+
+
+def device_x_row(width: int):
+    """The x of every point of a line (``ref.row_xs``) as an f32
+    [1, width] array on this process's default device, put there once per
+    width and shared by every worker thread.
+    Each upload counts in the ``band_row_uploads`` counter, which a node's
+    heartbeat carries: more than one a width means the cache is not
+    holding."""
+    row = _X_ROWS.get(width)
+    if row is not None:
+        return row
+    with _X_ROWS_LOCK:
+        row = _X_ROWS.get(width)
+        if row is None:
+            import jax
+
+            from repro.cluster.telemetry import counters
+            from repro.kernels.mandelbrot.ref import row_xs
+
+            row = _X_ROWS[width] = jax.device_put(row_xs(width)[None, :])
+            counters.add("band_row_uploads")
+    return row
+
+
 def warm_spec(*, width: int, max_iters: int, interpret: bool,
               first_line: int = 0, platform: str | None = None
               ) -> ClusterSpec:
     """A one-item job that reports the device of the node that runs it and
-    renders there the band of ``LINES_PER_ITEM`` lines from ``first_line``,
-    as the farm's work function does: the first call at the band shape,
-    timed as ``first_call_ms`` (trace + compile + one band), warms the
-    farm's kernel on the node, and the band's iterations come back as
-    ``band_iters`` for a point-by-point check.
+    renders there the band of ``LINES_PER_ITEM`` lines from ``first_line``
+    with the kernel itself, point by point (the farm's band program returns
+    only the band's sums): the first call at the band shape is timed as
+    ``first_call_ms`` (trace + compile + one band), and the band's
+    iterations come back as ``band_iters`` for a point-by-point check.
 
     With ``platform``, a node whose first device is another platform skips
     the kernel and only reports, so a caller can refuse it quickly."""

@@ -69,7 +69,7 @@ from typing import Any, Callable, Sequence
 
 from repro.cluster import peer as peer_mod
 from repro.cluster.netchannels import ChannelClosed
-from repro.cluster.telemetry import spans
+from repro.cluster.telemetry import counters, spans
 from repro.cluster.wire import (
     APP_WIRE_CHANNEL,
     CODE_CACHE_SLOTS,
@@ -194,7 +194,8 @@ def run_node(
     report = {"booting": bool(preload), "boot_ms": 0.0, "backend_ms": None,
               "load_ms": 0.0, "items": 0, "cache_hits": 0,
               "cache_misses": 0, "jobs_bound": 0}
-    # Slow-moving, so they ride the heartbeat alone and not every REPORT.
+    # Slow-moving, so they ride the heartbeat alone and not every REPORT,
+    # with the process's once-only counters (``counters``) beside them.
     compiles = {}
     for key in COMPILE_EVENTS:
         compiles[key] = 0
@@ -269,7 +270,7 @@ def run_node(
         conn.send(Frame(
             FrameType.HEARTBEAT,
             {"node_id": node_id, "report": snapshot_report(),
-             "compiles": dict(compiles)},
+             "compiles": {**compiles, **counters.snapshot()}},
             LOAD_WIRE_CHANNEL,
         ))
 

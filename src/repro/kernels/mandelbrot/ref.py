@@ -42,16 +42,34 @@ def mandelbrot_reference(x0: jax.Array, y0: jax.Array, max_iters: int):
     return iters, colour
 
 
+def row_xs(width: int, *, min_x=-2.5, range_x=3.5):
+    """The x coordinate of each of a line's ``width`` points, in f32: the
+    same for every line of the paper's layout {4:26-39}."""
+    delta = range_x / width
+    return (np.float32(min_x)
+            + np.arange(width, dtype=np.float32) * np.float32(delta))
+
+
+def line_ys(height: int, width: int, *, first_line: int = 0, min_y=1.0,
+            range_x=3.5):
+    """The y coordinate of lines ``first_line .. first_line + height - 1``
+    of the paper's layout, in f32: computed in f64, then cast, so a band's
+    values equal the same lines' of the whole grid bit for bit."""
+    delta = range_x / width
+    lines = np.arange(first_line, first_line + height)
+    return (min_y - lines * delta).astype(np.float32)
+
+
 def grid_coords(height: int, width: int, *, first_line: int = 0,
                 min_x=-2.5, min_y=1.0, range_x=3.5):
     """The paper's ``createInstance`` coordinate layout {4:26-39} for lines
     ``first_line .. first_line + height - 1``: two f32 [height, width] NumPy
-    grids.  Built on the host so that every band of a farm and the whole
-    grid of its oracle get bit-identical coordinates on any device."""
-    delta = range_x / width
-    x = np.float32(min_x) + np.arange(width, dtype=np.float32) * np.float32(delta)
-    lines = np.arange(first_line, first_line + height)
-    y = (min_y - lines * delta).astype(np.float32)
+    grids of :func:`row_xs` and :func:`line_ys`.  Built on the host so that
+    every band of a farm and the whole grid of its oracle get bit-identical
+    coordinates on any device."""
+    x = row_xs(width, min_x=min_x, range_x=range_x)
+    y = line_ys(height, width, first_line=first_line, min_y=min_y,
+                range_x=range_x)
     return (np.broadcast_to(x, (height, width)).copy(),
             np.broadcast_to(y[:, None], (height, width)).copy())
 

@@ -1,6 +1,6 @@
 """The main path's kernel compiles for a TPU v5e that is described, not
 attached: the Mandelbrot kernel at the farm's band shape and at the paper's
-whole grid, with the paper's escape value.
+whole grid, and the farm's band program, with the paper's escape value.
 
 The topology is described inside a module fixture, never while a module is
 imported, so every test worker collects the same tests and only the one
@@ -10,6 +10,7 @@ be read back without one.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,13 +18,15 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+from bench.readout import KERNEL_EVENT
+
 from repro.apps.mandelbrot_farm import (
     LINES_PER_ITEM,
     PAPER_LINES,
     PAPER_MAX_ITERS,
     PAPER_WIDTH,
 )
-from repro.kernels.mandelbrot.ops import mandelbrot
+from repro.kernels.mandelbrot.ops import band_totals, mandelbrot
 
 
 @pytest.fixture(scope="module")
@@ -65,3 +68,20 @@ def test_mandelbrot_compiles_for_v5e(shape, one_chip, no_persistent_cache):
                                   interpret=False)
     ).lower(coords, coords).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_band_program_compiles_for_v5e_with_one_kernel_call(
+        one_chip, no_persistent_cache):
+    """The band program at the paper's band holds the kernel as exactly one
+    custom call under the name the benchmark's kernel metrics look for in
+    the device trace."""
+    x_row = jax.ShapeDtypeStruct((1, PAPER_WIDTH), jnp.float32,
+                                 sharding=one_chip)
+    y_col = jax.ShapeDtypeStruct((LINES_PER_ITEM, 1), jnp.float32,
+                                 sharding=one_chip)
+    compiled = band_totals.lower(x_row, y_col, max_iters=PAPER_MAX_ITERS,
+                                 interpret=False).compile()
+    kernels = [line for line in compiled.as_text().splitlines()
+               if re.match(KERNEL_EVENT, line.strip())]
+    assert len(kernels) == 1, kernels
+    assert "tpu_custom_call" in kernels[0]

@@ -12,10 +12,18 @@ import pytest
 
 from repro.apps.mandelbrot_farm import (
     LINES_PER_ITEM,
+    device_x_row,
     farm_grid,
+    farm_spec,
     farm_totals,
 )
-from repro.kernels.mandelbrot.ref import grid_coords, line_coords
+from repro.kernels.mandelbrot.ops import band_totals, mandelbrot
+from repro.kernels.mandelbrot.ref import (
+    grid_coords,
+    line_coords,
+    line_ys,
+    mandelbrot_reference,
+)
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 
@@ -87,6 +95,118 @@ def test_band_coords_tile_the_whole_grid():
     assert x.dtype == y.dtype == np.float32
 
 
+@pytest.mark.parametrize("width,max_iters,bands", [
+    (256, 48, range(10)),  # every band of a 40-line plane
+    (5600, 6, (0, 399, 799)),  # the paper's width: first, middle, last
+], ids=["small_plane", "paper_width"])
+def test_band_program_matches_three_step_path_and_oracle(width, max_iters,
+                                                         bands):
+    """Band by band, the one-call band program (as the farm's work
+    function runs it) returns what the three-step path (host grids, the
+    kernel, two sums read back) and the jnp oracle return; the device-held
+    x row and the band's y values are ``grid_coords``' own, bit for bit."""
+    import jax.numpy as jnp
+
+    spec = farm_spec(1, 1, lines=4 * LINES_PER_ITEM, width=width,
+                     max_iters=max_iters, interpret=True)
+    work = spec.node_net.group.function
+    row = np.asarray(device_x_row(width))
+    assert row.shape == (1, width) and row.dtype == np.float32
+    for band in bands:
+        first = band * LINES_PER_ITEM
+        x0, y0 = grid_coords(LINES_PER_ITEM, width, first_line=first)
+        ys = line_ys(LINES_PER_ITEM, width, first_line=first)
+        np.testing.assert_array_equal(np.broadcast_to(row, x0.shape), x0)
+        np.testing.assert_array_equal(
+            np.broadcast_to(ys[:, None], y0.shape), y0)
+        assert ys.dtype == np.float32
+
+        iters, colour = mandelbrot(x0, y0, max_iters=max_iters,
+                                   interpret=True)
+        three_step = (int(jnp.sum(iters)), int(jnp.sum(colour)), colour.size)
+        ref_iters, ref_colour = mandelbrot_reference(
+            jnp.asarray(x0), jnp.asarray(y0), max_iters)
+        oracle = farm_totals(np.asarray(ref_iters), np.asarray(ref_colour))
+        totals = band_totals(device_x_row(width), ys[:, None],
+                             max_iters=max_iters, interpret=True)
+        assert totals.dtype == jnp.int32 and totals.shape == (2,)
+        answer = work(band)
+        assert answer == three_step == oracle, band
+        assert tuple(np.asarray(totals).tolist()) == answer[:2]
+        assert all(type(v) is int for v in answer)
+
+
+def test_device_x_row_is_put_once_under_racing_workers():
+    """More threads than cores ask for a new width's x row at once, with a
+    short switch interval: every one gets the same device array, and the
+    process counts one upload."""
+    import os
+    import sys
+    import threading
+
+    from repro.cluster.telemetry import counters
+
+    width = 4224  # no other test puts this width on the device
+    n = (os.cpu_count() or 1) + 1
+    before = counters.snapshot().get("band_row_uploads", 0)
+    rows, start = [], threading.Barrier(n)
+
+    def worker():
+        start.wait(timeout=30)
+        rows.append(device_x_row(width))
+
+    threads = [threading.Thread(target=worker) for _ in range(n)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert len(rows) == n and all(row is rows[0] for row in rows)
+    assert counters.snapshot()["band_row_uploads"] - before == 1
+
+
+def test_band_program_compiles_once_across_line_counts():
+    """A 4-band farm and then a 12-band farm through one node process:
+    the band program's jit cache holds one entry, the node put the x row
+    on its device once (``band_row_uploads`` on its heartbeat reads 1),
+    and the second farm traced and compiled nothing."""
+    from repro.cluster.service import ClusterService
+
+    def jit_entries(_item):
+        from repro.kernels.mandelbrot.ops import band_totals
+
+        return band_totals._cache_size()
+
+    width, max_iters = 256, 32
+    x0, y0 = farm_grid(12 * LINES_PER_ITEM, width)
+    iters, colour = mandelbrot_reference(x0, y0, max_iters)
+    iters, colour = np.asarray(iters), np.asarray(colour)
+    svc = ClusterService(nodes=1, workers=2,
+                         preload=("repro.kernels.mandelbrot.ops",))
+    counts = []
+    with svc:
+        for bands in (4, 12):
+            lines = bands * LINES_PER_ITEM
+            spec = farm_spec(1, 2, lines=lines, width=width,
+                             max_iters=max_iters, interpret=True)
+            assert svc.submit(spec, timeout=120).result() == farm_totals(
+                iters[:lines], colour[:lines])
+            time.sleep(1.2)  # a heartbeat carries the node's counts
+            counts.append(dict(
+                svc.metrics_snapshot()["nodes"]["node0"]["compiles"]))
+        entries = svc.submit(_one_item(jit_entries), timeout=60).result()
+    assert entries == 1
+    assert counts[0]["band_row_uploads"] == counts[1]["band_row_uploads"] == 1
+    for key in ("jaxpr_traces", "backend_compiles"):
+        assert counts[0][key] >= 1
+        assert counts[1][key] == counts[0][key], key
+
+
 def _one_item(work):
     """A job of one item whose work function is ``work``: how spans are
     turned on and collected in a node-loader."""
@@ -112,7 +232,6 @@ def test_farm_spans_cover_every_band_and_add_no_wire_bytes(monkeypatch):
     One worker, no prefetch and a flush per result make each band one
     WORK_BATCH and one RESULT_BATCH of one item, so bytes per band repeat
     exactly."""
-    from repro.apps.mandelbrot_farm import farm_spec
     from repro.cluster.service import ClusterService
     from repro.cluster.telemetry import spans
     from repro.cluster.wire import FrameConnection, FrameType
