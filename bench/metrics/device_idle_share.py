@@ -1,5 +1,6 @@
 """device_idle_share (%): the share of the traced slice in which no
-operation ran on the chip (node's device trace)."""
+operation ran on the chip (each node's device trace, over its own window;
+averaged over the nodes)."""
 
 
 def read(run):

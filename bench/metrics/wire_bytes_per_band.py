@@ -1,6 +1,6 @@
-"""wire_bytes_per_band (B): bytes both ways on the node's connection
-(the program's WireCounters) over the traced slice, per band collected
-in it."""
+"""wire_bytes_per_band (B): bytes both ways on the nodes' connections
+(the program's WireCounters, summed over the nodes) over the traced slice,
+per band collected in it."""
 
 
 def read(run):

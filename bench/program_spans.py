@@ -177,7 +177,7 @@ def program_gaps(summary, ps: ProgramSpans, top: int = 10) -> list[list]:
                  for s in ps.spans]
     total: dict[str, float] = {}
     for chip in summary.chips:
-        gaps = trace_reduce.gaps_of(chip.busy, summary.window)
+        gaps = trace_reduce.gaps_of(chip.busy, chip.window)
         for (s, e), label in zip(gaps, trace_reduce.label_gaps(gaps,
                                                                intervals)):
             if label == trace_reduce.NO_HOST_EVENT:

@@ -33,6 +33,11 @@ def report(run: RunView, result: dict, log) -> None:
     log(f"cell={run.cell.name} jobs={len(rec.jobs)} "
         f"window_s={rec.window_s} bands_in_window={rec.window_bands()} "
         f"setup_s={rec.setup_s}")
+    by_node: dict[str, int] = {}
+    for job in rec.jobs:
+        for nid, n in (job.items_by_node or {}).items():
+            by_node[nid] = by_node.get(nid, 0) + n
+    log(f"bands of finished jobs by node: {by_node}")
     errors = [j.error for j in rec.jobs if j.error]
     if errors:
         log(f"jobs without an answer: {len(errors)}; first: {errors[:3]}")

@@ -5,25 +5,28 @@
 
 from the root of a checkout.  The cell is an entry of ``BENCHMARK.json``'s
 ``workloads``; its configuration, traffic mix and metric readers are found
-by name under ``bench/``.  Set-up (``setup_s``) runs from this process's
-start to the window's first timed submit: the pool's boot, the node's
-backend start, and two warm jobs at the cell's band shape (the first ships
-the code and compiles).  Then the traffic runs for ``--seconds``; the job
-still running at the close is waited for, the node reports its memory
-peak, and the pool closes.  Only then does this process touch JAX: it
-runs the plain reference on the chip and judges every job's answer
+by name under ``bench/``.  The configuration's ``nodes`` node-loaders each
+own the chips they find: one node on one chip, or one node per chip.
+Set-up (``setup_s``) runs from this process's start to the window's first
+timed submit: the pool's boot, the nodes' backend start, one band in
+every node (which ships the code and compiles there), and two warm jobs
+at the cell's band shape.  Then the traffic runs for ``--seconds``; the
+job still running at the close is waited for, every node reports its
+memory peak, and the pool closes.  Only then does this process touch JAX:
+it runs the plain reference on the chip and judges every job's answer
 (``bench/judge.py``).
 
 With ``--trace 0`` the metrics are the cell's end-to-end metrics; with
-``--trace 1`` the node traces a slice of the window and the metrics are
-the cell's per-layer ones, with the device's busy time and a breakdown.
+``--trace 1`` every node traces a slice of the window and the metrics are
+the cell's per-layer ones, with the devices' busy time and a breakdown.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown``),
 and last ``checks``, each number compared with its limit; the same
-numbers close standard error.  No accelerator, too few chips, a device
-kind missing from ``bench/devices.json``, or an unknown workload: exit
-code 1 or 2 and no result line.
+numbers close standard error.  No accelerator, too few chips, a node of
+several that does not own exactly one chip, a device kind missing from
+``bench/devices.json``, or an unknown workload: exit code 1 or 2 and no
+result line.
 """
 
 from __future__ import annotations
@@ -76,7 +79,7 @@ def run(root: pathlib.Path, args: argparse.Namespace, *, t_start: float,
                        platform=platform, device_kinds=device_kinds,
                        spec_factory=spec_factory, log=log)
 
-    # The pool has closed and its node has exited: the chip is free.
+    # The pool has closed and its nodes have exited: the chips are free.
     import jax
 
     if platform is not None and jax.devices()[0].platform != platform:

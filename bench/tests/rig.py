@@ -2,7 +2,7 @@
 holding a ``BENCHMARK.json`` whose cells use the test-only configuration
 ``data/mandelbrot_tiny.json`` (the farm shrunk, the kernel in the Pallas
 interpreter), the benchmark's own traffic mixes and metric readers, and
-whatever mixes a test adds."""
+whatever mixes and configuration changes a test adds."""
 
 from __future__ import annotations
 
@@ -16,10 +16,12 @@ TINY = "mandelbrot_tiny"
 
 
 def make_root(tmp: pathlib.Path, workloads: list[dict],
-              traffic: dict[str, dict] | None = None) -> pathlib.Path:
+              traffic: dict[str, dict] | None = None,
+              config: dict | None = None) -> pathlib.Path:
     """A root under ``tmp`` with the benchmark's metrics and mixes, the
-    tiny configuration, the extra mixes ``traffic``, and ``workloads`` as
-    its only cells.  A metric that lists cells lists every one of them."""
+    tiny configuration with the keys of ``config`` changed, the extra mixes
+    ``traffic``, and ``workloads`` as its only cells.  A metric that lists
+    cells lists every one of them."""
     root = tmp / "checkout"
     (root / "bench" / "configs").mkdir(parents=True)
     for d in ("metrics", "traffic"):
@@ -27,8 +29,9 @@ def make_root(tmp: pathlib.Path, workloads: list[dict],
     for name, mix in (traffic or {}).items():
         (root / "bench" / "traffic" / f"{name}.json").write_text(
             json.dumps(mix))
-    shutil.copy(BENCH / "tests" / "data" / f"{TINY}.json",
-                root / "bench" / "configs" / f"{TINY}.json")
+    tiny = json.loads((BENCH / "tests" / "data" / f"{TINY}.json").read_text())
+    (root / "bench" / "configs" / f"{TINY}.json").write_text(
+        json.dumps({**tiny, **(config or {})}))
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     for m in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" in m:
@@ -41,9 +44,9 @@ def make_root(tmp: pathlib.Path, workloads: list[dict],
     return root
 
 
-def cell(name: str, traffic: str) -> dict:
-    return {"name": name, "config": TINY, "traffic": traffic, "chips": 1,
-            "why": "CPU rehearsal"}
+def cell(name: str, traffic: str, chips: int = 1) -> dict:
+    return {"name": name, "config": TINY, "traffic": traffic,
+            "chips": chips, "why": "CPU rehearsal"}
 
 
 def last_json_line(text: str) -> dict | None:

@@ -1,6 +1,8 @@
 """The benchmark's parts on their own: the traffic reader, the judge,
 the reference against the program's own oracle, the metric readers, and
-the refusal of a device kind that the device table lacks."""
+the refusal of a pool whose devices the cell cannot use: a device kind
+that the device table lacks, two nodes in one process, a node of several
+that owns more than one chip."""
 
 import gc
 import json
@@ -125,7 +127,8 @@ class _FakeJob:
 
     def stats(self):
         return {"items_collected": 1, "submit_to_first_result_ms": 2.5,
-                "code_shipped": 0, "duplicates_dropped": 0}
+                "code_shipped": 0, "duplicates_dropped": 0,
+                "nodes": {"node0": {"items": 1}}}
 
 
 class _FakeService:
@@ -148,6 +151,7 @@ def test_the_closed_loop_keeps_each_answer_and_leaves_the_running_job():
     assert [j.collected() for j in jobs] == [2, 0, 2, 1]
     assert jobs[0].done_at is not None and jobs[3].done_at is None
     assert jobs[0].first_result_ms == 2.5 and jobs[3].first_result_ms is None
+    assert jobs[0].items_by_node == {"node0": 1}
 
 
 class _FakeHandle:
@@ -159,28 +163,35 @@ class _FakeHandle:
 
 
 class _FakePool:
-    """Answers the device probe as a chip of an unknown kind would."""
+    """A pool of one node that answers the device probe with ``report``."""
+
+    workers = 1
 
     def __init__(self, report):
         self.report = report
         self.jobs = 0
 
+    def pool_alive(self):
+        return ["node0"]
+
     def submit(self, spec, **_kw):
         self.jobs += 1
-        return _FakeHandle(self.report)
+        return _FakeHandle([("node0", self.report)])
 
 
 def test_a_device_kind_missing_from_the_table_is_an_error():
     kinds = json.loads((rig.BENCH / "devices.json").read_text())
     assert "TPU v5 lite" in kinds
     cell = types.SimpleNamespace(chips=1)
-    pool = _FakePool({"platform": "tpu", "kind": "TPU v99", "count": 1})
+    pool = _FakePool({"platform": "tpu", "kind": "TPU v99", "count": 1,
+                      "pid": 7})
     with pytest.raises(KeyError, match="TPU v99"):
         drive.boot(pool, cell, None, platform="tpu", device_kinds=kinds,
                    log=lambda _m: None)
     assert pool.jobs == 1  # refused before any warm-up
-    for report in ({"platform": "cpu", "kind": "cpu", "count": 1},
-                   {"platform": "tpu", "kind": "TPU v5 lite", "count": 0}):
+    for report in ({"platform": "cpu", "kind": "cpu", "count": 1, "pid": 7},
+                   {"platform": "tpu", "kind": "TPU v5 lite", "count": 0,
+                    "pid": 7}):
         with pytest.raises(drive.NoAccelerator):
             drive.boot(_FakePool(report), cell, None, platform="tpu",
                        device_kinds=kinds, log=lambda _m: None)
@@ -205,3 +216,60 @@ def test_the_gc_watch_keeps_long_collections_only(monkeypatch):
         assert probes.gc_pauses(0) == pauses
     finally:
         probes.gc.callbacks.remove(probes._on_gc)
+
+
+class _TwoNodes(_FakePool):
+    def pool_alive(self):
+        return ["node0", "node1"]
+
+
+@pytest.mark.parametrize("reports, error", [
+    ({"node0": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+                "pid": 7},
+      "node1": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+                "pid": 7}}, "one process"),
+    ({"node0": {"platform": "tpu", "kind": "TPU v5 lite", "count": 2,
+                "pid": 7},
+      "node1": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+                "pid": 8}}, "node0 owns 2 devices"),
+    ({"node0": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+                "pid": 7},
+      "node1": {"platform": "cpu", "kind": "cpu", "count": 1,
+                "pid": 8}}, "node1 runs on 'cpu'"),
+])
+def test_a_pool_of_two_nodes_is_refused_unless_each_owns_one_chip(
+        monkeypatch, reports, error):
+    kinds = json.loads((rig.BENCH / "devices.json").read_text())
+    asked = []
+
+    def every_node(svc, work, args, **_kw):
+        asked.append((work, sorted(args)))
+        return reports
+
+    monkeypatch.setattr(drive, "every_node", every_node)
+    pool = _TwoNodes(None)
+    with pytest.raises(drive.NoAccelerator, match=error):
+        drive.boot(pool, types.SimpleNamespace(chips=2), None,
+                   platform="tpu", device_kinds=kinds, log=lambda _m: None)
+    from bench import probes
+
+    assert asked == [(probes.device_report, ["node0", "node1"])]
+    assert pool.jobs == 0  # refused before any warm-up
+
+
+def test_a_pool_of_one_chip_a_node_sums_its_devices():
+    kinds = json.loads((rig.BENCH / "devices.json").read_text())
+    reports = {f"node{i}": {"platform": "tpu", "kind": "TPU v5 lite",
+                            "count": 1, "pid": 10 + i} for i in range(4)}
+    device = drive.pool_devices(reports, 4, platform="tpu",
+                                device_kinds=kinds)
+    assert device == {"platform": "tpu", "kind": "TPU v5 lite", "count": 4,
+                      "pids": [10, 11, 12, 13]}
+    with pytest.raises(drive.NoAccelerator, match="3 device"):
+        drive.pool_devices(dict(list(reports.items())[:3]), 4,
+                           platform="tpu", device_kinds=kinds)
+    # One node may own several chips: the check stays count >= chips.
+    one = {"node0": {"platform": "tpu", "kind": "TPU v5 lite", "count": 4,
+                     "pid": 10}}
+    assert drive.pool_devices(one, 4, platform="tpu",
+                              device_kinds=kinds)["count"] == 4

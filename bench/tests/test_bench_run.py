@@ -1,8 +1,10 @@
 """The benchmark's command rehearsed on the CPU: a cell added by files and
 a ``workloads`` entry alone runs end to end through a real one-node
 ``ClusterService`` (kernel in the Pallas interpreter) and prints a
-well-formed last line; an unknown workload, and a checkout without the
-program, exit non-zero and print no result."""
+well-formed last line; so does a cell of two nodes, each of its own
+device, with bands from both; a control job reaches every node of a real
+pool once; an unknown workload, and a checkout without the program, exit
+non-zero and print no result."""
 
 import json
 import os
@@ -11,7 +13,7 @@ import shutil
 import subprocess
 import sys
 
-from bench import run_cell
+from bench import drive, probes, run_cell
 from bench.tests import rig
 
 RESULT_KEYS = ["correct", "attempted", "failed", "metrics", "device",
@@ -47,6 +49,64 @@ def test_a_cell_added_by_files_alone_runs_end_to_end_on_cpu(
     assert [ln.split("=")[0] for ln in tail] == [
         f"check {k}" for k in result["checks"]]
     assert (root / "bench" / ".cache" / "xla").is_dir()
+
+
+def test_a_cell_of_two_nodes_runs_end_to_end_on_cpu(tmp_path, monkeypatch,
+                                                   capsys):
+    root = rig.make_root(tmp_path, [rig.cell("tiny.two", "batch", chips=2)],
+                         config={"nodes": 2})
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "")
+    recordings = []
+    record = drive.record
+
+    def keep(*args, **kwargs):
+        recordings.append(record(*args, **kwargs))
+        return recordings[-1]
+
+    monkeypatch.setattr(drive, "record", keep)
+    rc = run_cell.main(["--workload", "tiny.two", "--seed", str(2**31 + 5),
+                        "--seconds", "1.5", "--trace", "0"],
+                       root=root, platform=None)
+    out = capsys.readouterr()
+    assert rc == 0, out.err[-3000:]
+    result = rig.last_json_line(out.out)
+    assert result["correct"] is True and result["failed"] == 0
+    assert (result["device"]["platform"], result["device"]["count"]) == (
+        "cpu", 2)
+    [rec] = recordings
+    assert len(set(rec.device["pids"])) == 2
+    bands: dict[str, int] = {}
+    for job in rec.jobs:
+        for nid, n in (job.items_by_node or {}).items():
+            bands[nid] = bands.get(nid, 0) + n
+    assert set(bands) == {"node0", "node1"} and min(bands.values()) > 0
+    assert set(rec.gc_pauses) == {"host", "node0", "node1"}
+
+
+def test_a_control_job_reaches_every_node_once(tmp_path, monkeypatch):
+    from repro.cluster.service import ClusterService
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    with ClusterService(nodes=2, workers=2) as svc:
+        nodes = svc.pool_alive()
+        assert nodes == ["node0", "node1"]
+        # A per-node argument reaches its own node: ``str`` echoes it.
+        assert drive.every_node(svc, str, {"node0": "a", "node1": "b"}) == {
+            "node0": "a", "node1": "b"}
+        reports = drive.every_node(svc, probes.device_report,
+                                   dict.fromkeys(nodes, 0))
+        assert sorted(reports) == nodes
+        assert len({r["pid"] for r in reports.values()}) == 2
+        # Two items a node, one draw a node: every item of a node answers
+        # what its first one drew.
+        spec = probes.every_node_spec(os.urandom, dict.fromkeys(nodes, 16),
+                                      2, str(tmp_path))
+        answers = svc.submit(spec).result()
+        drawn = dict(answers)
+        assert len(answers) == 4 and sorted(drawn) == nodes
+        assert all(drawn[nid] == answer for nid, answer in answers)
+        assert len(set(drawn.values())) == 2
+        assert sorted(os.listdir(tmp_path)) == nodes
 
 
 def test_an_unknown_workload_is_refused(tmp_path, monkeypatch, capsys):

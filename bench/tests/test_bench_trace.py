@@ -1,9 +1,11 @@
 """The reduction from a profiler trace to the per-layer metrics: its
-interval arithmetic on synthetic events, and the whole reduction on a
-small trace recorded on a TPU v5e (``data/paper_band_trace.xplane.pb.gz``,
-written by ``record_trace.py``)."""
+interval arithmetic on synthetic events, the whole reduction on a small
+trace recorded on a TPU v5e (``data/paper_band_trace.xplane.pb.gz``,
+written by ``record_trace.py``), and one such file per node merged."""
 
+import gzip
 import pathlib
+import statistics
 import types
 
 import pytest
@@ -83,3 +85,59 @@ def test_the_recorded_chip_trace_reduces_to_its_twelve_bands():
     assert 1 <= len(gaps) <= 10
     idle = sum(s for _, s in summary.idle_gaps(top=10**6))
     assert idle == pytest.approx(summary.window_s - summary.busy_s)
+
+
+TRACE = gzip.decompress(FIXTURE.read_bytes())
+
+
+def _node_dirs(tmp_path, nodes):
+    """The recorded trace as each node of ``nodes`` writes it."""
+    for node in nodes:
+        profile = tmp_path / node / "plugins" / "profile" / "1"
+        profile.mkdir(parents=True)
+        (profile / "host.xplane.pb").write_bytes(TRACE)
+    return str(tmp_path)
+
+
+def test_one_node_file_reads_as_the_file_itself(tmp_path):
+    one = tr.read_file(str(FIXTURE))
+    loaded = tr.load(_node_dirs(tmp_path, ["node0"]))
+    assert len(loaded.chips) == 1
+    assert (loaded.busy_s, loaded.window_s, loaded.idle_share) == (
+        one.busy_s, one.window_s, one.idle_share)
+    assert loaded.kernel_events(KERNEL_EVENT) == one.kernel_events(
+        KERNEL_EVENT)
+    assert loaded.launch_gaps_ns(KERNEL_EVENT) == one.launch_gaps_ns(
+        KERNEL_EVENT)
+    assert loaded.device_ops() == one.device_ops()
+    assert loaded.idle_gaps() == one.idle_gaps()
+
+
+def test_a_file_per_node_is_read_chip_by_chip(tmp_path):
+    one = tr.read_file(str(FIXTURE))
+    both = tr.load(_node_dirs(tmp_path, ["node0", "node1"]))
+    assert len(both.chips) == 2
+    assert both.idle_share == one.idle_share
+    assert both.busy_s == pytest.approx(one.busy_s)
+    assert both.window_s == one.window_s
+    assert len(both.kernel_events(KERNEL_EVENT)) == 2 * len(
+        one.kernel_events(KERNEL_EVENT))
+    gaps = both.launch_gaps_ns(KERNEL_EVENT)
+    assert len(gaps) == 2 * len(one.launch_gaps_ns(KERNEL_EVENT))
+    assert statistics.median(gaps) == statistics.median(
+        one.launch_gaps_ns(KERNEL_EVENT))
+    idle = dict(both.idle_gaps(top=10**6))
+    assert idle.keys() == dict(one.idle_gaps(top=10**6)).keys()
+    for name, seconds in one.idle_gaps(top=10**6):
+        assert idle[name] == pytest.approx(2 * seconds)
+    assert [n for n, _ in both.device_ops()] == [
+        n for n, _ in one.device_ops()]
+
+
+def test_two_traces_of_one_node_are_refused(tmp_path):
+    root = pathlib.Path(_node_dirs(tmp_path, ["node0"]))
+    (root / "node0" / "again.xplane.pb").write_bytes(TRACE)
+    with pytest.raises(tr.NoDeviceTrace, match="node0"):
+        tr.load(str(root))
+    with pytest.raises(tr.NoDeviceTrace):
+        tr.load(str(tmp_path / "node0" / "empty"))

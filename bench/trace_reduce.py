@@ -1,14 +1,17 @@
-"""Reduce a profiler trace of the node to the numbers the metrics read.
+"""Reduce the profiler traces of the nodes to the numbers the metrics read.
 
-The node that holds the chip writes one ``.xplane.pb`` per traced slice.
-Its device planes (``/device:TPU:<n>``) carry, on their ``XLA Ops`` line,
-one event per device operation; its host plane (``/host:CPU``) carries one
-line per host thread with the runtime's own events (dispatch, transfers,
-waits).  From these:
+Each node-loader writes one ``.xplane.pb`` per traced slice, under a
+directory of its own, for the chips it owns: one node on one chip, or one
+node per chip in a cell of several.  A file's device planes
+(``/device:TPU:<n>``) carry, on their ``XLA Ops`` line, one event per
+device operation; its host plane (``/host:CPU``) carries one line per host
+thread with the node's runtime events (dispatch, transfers, waits).  Each
+chip is read against its own file's window and host events:
 
 - busy time: the union of the device operations' intervals, per chip;
-- idle gaps: the rest of the traced window, each labelled by what the
-  host was doing at its middle (the shortest host event that covers it);
+- idle gaps: the rest of the chip's traced window, each labelled by what
+  its node's host was doing at its middle (the shortest host event that
+  covers it);
 - kernel events: the device operations whose name matches the kernel's
   pattern, and the device-idle time between consecutive ones.
 """
@@ -23,7 +26,7 @@ import os
 import re
 import statistics
 from collections import Counter
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
@@ -44,14 +47,12 @@ class Interval:
 
 @dataclass
 class ChipTrace:
+    """One chip's device operations, with the window and host events of
+    the trace file that holds it: its node's."""
+
     ops: list[Interval]
-    busy: list[tuple[float, float]] = field(default_factory=list)
-
-
-@dataclass
-class TraceSummary:
+    busy: list[tuple[float, float]]
     window: tuple[float, float]  # ns
-    chips: list[ChipTrace]
     host: list[Interval]
 
     @property
@@ -60,13 +61,37 @@ class TraceSummary:
 
     @property
     def busy_s(self) -> float:
-        """Seconds with an operation on the device, averaged over chips."""
-        return statistics.fmean(
-            sum(e - s for s, e in chip.busy) for chip in self.chips) * 1e-9
+        """Seconds with an operation on the chip."""
+        return sum(e - s for s, e in self.busy) * 1e-9
 
     @property
     def idle_share(self) -> float:
         return 1.0 - self.busy_s / self.window_s
+
+
+@dataclass
+class TraceSummary:
+    """The traced chips of every node, each read against its own file's
+    window and host events; ``window`` and ``host`` span every file."""
+
+    window: tuple[float, float]  # ns
+    chips: list[ChipTrace]
+    host: list[Interval]
+
+    @property
+    def window_s(self) -> float:
+        """The chips' traced windows, averaged."""
+        return statistics.fmean(chip.window_s for chip in self.chips)
+
+    @property
+    def busy_s(self) -> float:
+        """Seconds with an operation on the device, averaged over chips."""
+        return statistics.fmean(chip.busy_s for chip in self.chips)
+
+    @property
+    def idle_share(self) -> float:
+        """Each chip's idle share over its own window, averaged."""
+        return statistics.fmean(chip.idle_share for chip in self.chips)
 
     def kernel_events(self, pattern: str) -> list[Interval]:
         rx = re.compile(pattern)
@@ -96,12 +121,12 @@ class TraceSummary:
         return [[name, ns * 1e-9] for name, ns in total.most_common(top)]
 
     def idle_gaps(self, top: int = 10) -> list[list]:
-        """Idle time by what the host was doing meanwhile, in seconds
-        summed over the chips' gaps."""
+        """Idle time by what the chip's node was doing meanwhile, in
+        seconds summed over the chips' gaps."""
         total: Counter = Counter()
         for chip in self.chips:
-            gaps = gaps_of(chip.busy, self.window)
-            for (s, e), label in zip(gaps, label_gaps(gaps, self.host)):
+            gaps = gaps_of(chip.busy, chip.window)
+            for (s, e), label in zip(gaps, label_gaps(gaps, chip.host)):
                 total[label] += e - s
         return [[name, ns * 1e-9] for name, ns in total.most_common(top)]
 
@@ -164,27 +189,26 @@ def _events(line) -> list[Interval]:
 
 
 def summarise(profile) -> TraceSummary:
-    """Reduce a ``jax.profiler.ProfileData`` of the node."""
-    chips, host, spans = [], [], []
+    """Reduce a ``jax.profiler.ProfileData`` of one node."""
+    devices, host, spans = [], [], []
     for plane in profile.planes:
         if DEVICE_PLANE.match(plane.name):
             ops = [ev for line in plane.lines if line.name == OPS_LINE
                    for ev in _events(line)]
             if ops:
-                chips.append(ChipTrace(ops, union((o.start, o.end)
-                                                  for o in ops)))
+                devices.append(sorted(ops, key=lambda o: o.start))
                 spans += [min(o.start for o in ops), max(o.end for o in ops)]
         elif plane.name == HOST_PLANE:
             for line in plane.lines:
                 host += _events(line)
-    if not chips:
+    if not devices:
         raise NoDeviceTrace("the trace has no operation on a TPU device")
     host.sort(key=lambda h: h.start)
     if host:
         spans += [host[0].start, max(h.end for h in host)]
     window = (min(spans), max(spans))
-    for chip in chips:
-        chip.ops.sort(key=lambda o: o.start)
+    chips = [ChipTrace(ops, union((o.start, o.end) for o in ops), window,
+                       host) for ops in devices]
     return TraceSummary(window, chips, host)
 
 
@@ -200,10 +224,21 @@ def read_file(path: str) -> TraceSummary:
 
 
 def load(trace_dir: str) -> TraceSummary:
-    """The one ``.xplane.pb`` under ``trace_dir``, reduced."""
-    paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                      recursive=True)
-    if len(paths) != 1:
-        raise NoDeviceTrace(f"expected one trace under {trace_dir}, found "
-                            f"{len(paths)}")
-    return read_file(paths[0])
+    """Every node's trace under ``trace_dir``, reduced: one ``.xplane.pb``
+    under each directory that ``trace_dir`` holds, one a node."""
+    by_node: dict[str, list[str]] = {}
+    for path in glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                          recursive=True):
+        node = os.path.relpath(path, trace_dir).split(os.sep)[0]
+        by_node.setdefault(node, []).append(path)
+    if not by_node:
+        raise NoDeviceTrace(f"no trace under {trace_dir}")
+    for node, paths in by_node.items():
+        if len(paths) != 1:
+            raise NoDeviceTrace(f"expected one trace under {node}, found "
+                                f"{len(paths)}")
+    files = [read_file(by_node[node][0]) for node in sorted(by_node)]
+    return TraceSummary(
+        (min(f.window[0] for f in files), max(f.window[1] for f in files)),
+        [chip for f in files for chip in f.chips],
+        sorted((h for f in files for h in f.host), key=lambda h: h.start))
