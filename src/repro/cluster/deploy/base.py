@@ -7,8 +7,10 @@ therefore concentrated in one question: *who starts that executable, where?*
 This module answers it with a small pluggable surface:
 
 * :class:`Launcher` — ``launch(node_id) -> NodeHandle`` plus a one-time
+  :meth:`Launcher.plan` (told how many nodes the pool starts with) and
   :meth:`Launcher.prepare` (told the host's connect address once the load
-  port is bound) and :meth:`Launcher.close`.
+  port is bound), :meth:`Launcher.relaunch` (a node in place of one that
+  was lost, on the chip it held) and :meth:`Launcher.close`.
 * :class:`NodeHandle` — ``poll``/``wait``/``kill``/``logs`` over one launched
   node-loader, however it is incarnated (subprocess, ssh session, thread).
 * :class:`PlacementPolicy` — what the host does when launches misbehave:
@@ -30,12 +32,19 @@ import abc
 from dataclasses import dataclass
 from typing import Sequence
 
+# How long a relaunch waits for a lost node bound to a chip to exit, and
+# so release its chip, before the replacement is launched.
+RELEASE_WAIT_S = 5.0
+
 
 class NodeHandle(abc.ABC):
     """One launched node-loader, however it runs (process, ssh, thread)."""
 
     node_id: str
     where: str  # human-readable placement, e.g. "local", "ssh:ws07", "thread"
+    # The accelerator chip the launcher bound this node to (its index on
+    # the machine), or None where the node owns whatever it finds.
+    chip: int | None = None
 
     @abc.abstractmethod
     def poll(self) -> int | None:
@@ -62,10 +71,21 @@ class NodeHandle(abc.ABC):
 class Launcher(abc.ABC):
     """Starts node-loaders somewhere; the host neither knows nor cares where.
 
-    Lifecycle: ``prepare(connect_host, port)`` once (after the host bound its
-    load port — launchers that ship code do it here), then ``launch`` per
-    node (and per respawn), then ``close`` at teardown.
+    Lifecycle: ``plan(nodes)`` and ``prepare(connect_host, port)`` once
+    (after the host bound its load port — launchers that ship code do it
+    here), then ``launch`` per node and ``relaunch`` per respawn or heal,
+    then ``close`` at teardown.
+
+    Chip binding: a launcher whose nodes share a machine with several
+    accelerator chips may give each node of a pool of several a chip of
+    its own (``NodeHandle.chip``).  A chip is free again once the handle
+    that held it has exited; ``relaunch`` frees the lost node's chip
+    before its replacement starts.
     """
+
+    def plan(self, nodes: int) -> None:
+        """Told the pool's size before its first launch (nothing by
+        default).  Nodes added later are launched into the same plan."""
 
     def prepare(self, connect_host: str, port: int) -> None:
         """Told the load-network address nodes must dial; sync code if the
@@ -88,8 +108,33 @@ class Launcher(abc.ABC):
         values) a respawn should steer clear of — the machine that already
         swallowed one launch silently."""
 
+    def relaunch(self, old: NodeHandle | None, node_id: str) -> NodeHandle:
+        """Start ``node_id`` in place of ``old`` (a launch that never
+        registered, or a node that died), away from ``old``'s placement.
+
+        A node bound to a chip is killed and waited for first, up to
+        :data:`RELEASE_WAIT_S`, so that its replacement finds the chip
+        free; an unbound one is killed (best effort) after the new launch.
+        """
+        avoid = (old.where,) if old is not None else ()
+        if old is not None and old.chip is not None:
+            _kill(old)
+            old.wait(timeout=RELEASE_WAIT_S)
+            return self.launch(node_id, avoid=avoid)
+        handle = self.launch(node_id, avoid=avoid)
+        if old is not None:
+            _kill(old)  # it never joined the network, or it died
+        return handle
+
     def close(self) -> None:  # pragma: no cover - trivial default
         """Release launcher-held resources (nothing by default)."""
+
+
+def _kill(handle: NodeHandle) -> None:
+    try:
+        handle.kill()
+    except Exception:  # best effort: it may be gone already
+        pass
 
 
 @dataclass

@@ -74,6 +74,7 @@ from __future__ import annotations
 
 import collections
 import hashlib
+import logging
 import queue
 import socket
 import threading
@@ -103,6 +104,8 @@ from repro.cluster.wire import (
 )
 from repro.core.timing import TimingCollector
 from repro.runtime.failures import HeartbeatMonitor, WorkFunctionError
+
+log = logging.getLogger(__name__)
 
 __all__ = ["HostLoader", "HostStats", "JobState", "WorkFunctionError"]
 
@@ -302,6 +305,7 @@ class HostLoader:
         placement: PlacementPolicy | None = None,
         expected_nodes: Sequence[str] | None = None,
         relaunch: Callable[[str, str], bool] | None = None,
+        node_running: Callable[[str], bool | None] | None = None,
         pool_nodes: int | None = None,
         pool_workers: int = 1,
         telemetry: Telemetry | None = None,
@@ -340,6 +344,10 @@ class HostLoader:
         # silent launch — and the reaper heal a mid-run death — without
         # knowing what a launcher is.
         self.relaunch = relaunch
+        # Deployment-layer probe: node_running(node_id) -> whether the
+        # node's process still runs (None: unknown), read when the host
+        # declares the node dead, to tell a false reap from a real loss.
+        self.node_running = node_running
         self._heals_used = 0
         # Chaos hook: every accepted connection is passed through this
         # wrapper (identity when None) before its reader thread starts, so
@@ -1152,6 +1160,15 @@ class HostLoader:
         the policy grants a heal — relaunch a replacement."""
         self.stats.deaths_detected += 1
         ev = rec.last_failure
+        in_flight = self._items_in_flight(rec.node_id)
+        running = (None if self.node_running is None
+                   else self.node_running(rec.node_id))
+        log.warning(
+            "node %s declared dead: %s s since its last heartbeat, %d items "
+            "in flight, process %s", rec.node_id,
+            round(ev.detect_latency_s, 3) if ev else None, in_flight,
+            {True: "still running", False: "exited",
+             None: "unknown"}[running])
         self.telemetry.inc("failures_detected")
         self.telemetry.emit(
             "failure",
@@ -1161,9 +1178,21 @@ class HostLoader:
             detect_latency_ms=(round(ev.detect_latency_s * 1e3, 3)
                                if ev else None),
             at_item=ev.step if ev else None,
+            items_in_flight=in_flight,
+            process_running=running,
         )
         self._requeue_node_items(rec.node_id)
         self._heal(rec)
+
+    def _items_in_flight(self, node_id: str) -> int:
+        """Items of active jobs dispatched, or peer-shipped, to the node and
+        not yet collected."""
+        return sum(
+            sum(1 for nid, _ in job.inflight[s].values() if nid == node_id)
+            + sum(1 for nid, _, _ in job.peer_inflight[s].values()
+                  if nid == node_id)
+            for job in self._jobs.values() if job.active
+            for s in range(job.S))
 
     def _requeue_node_items(self, node_id: str) -> bool:
         """Requeue every item a departed node can no longer deliver.

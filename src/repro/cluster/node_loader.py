@@ -168,6 +168,13 @@ def count_compiles(jax, counts: dict) -> None:
     jax.monitoring.register_event_duration_secs_listener(listener)
 
 
+def bound_chip(env) -> int | None:
+    """The one TPU chip the launcher bound this node to
+    (``TPU_VISIBLE_CHIPS`` naming a single chip), else None."""
+    chips = env.get("TPU_VISIBLE_CHIPS", "")
+    return int(chips) if chips.isdigit() else None
+
+
 def run_node(
     host: str,
     port: int,
@@ -192,8 +199,8 @@ def run_node(
     # nothing).  ``booting`` stays true until the preload (and the backend
     # start below) is done: the host counts no missed beats until then.
     report = {"booting": bool(preload), "boot_ms": 0.0, "backend_ms": None,
-              "load_ms": 0.0, "items": 0, "cache_hits": 0,
-              "cache_misses": 0, "jobs_bound": 0}
+              "chip": bound_chip(os.environ), "load_ms": 0.0, "items": 0,
+              "cache_hits": 0, "cache_misses": 0, "jobs_bound": 0}
     # Slow-moving, so they ride the heartbeat alone and not every REPORT,
     # with the process's once-only counters (``counters``) beside them.
     compiles = {}

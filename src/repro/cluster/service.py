@@ -347,6 +347,7 @@ class ClusterService:
             ),
             expected_nodes=node_ids,
             relaunch=self._relaunch,
+            node_running=self._node_running,
             pool_nodes=self.nodes,
             pool_workers=self.workers,
             telemetry=self.telemetry,
@@ -359,6 +360,7 @@ class ClusterService:
                 self.telemetry, host=self.http_host, port=self.http_port,
             )
         self.host_loader.start()
+        self.launcher.plan(self.nodes)
         self.launcher.prepare(self.bind_host, self.host_loader.port)
         for node_id in node_ids:
             self.handles[node_id] = self.launcher.launch(node_id)
@@ -389,20 +391,22 @@ class ClusterService:
         return hl.stats.items_total if hl is not None else 0
 
     def _relaunch(self, old_node_id: str, new_node_id: str) -> bool:
-        old = self.handles.get(old_node_id)
-        avoid = (old.where,) if old is not None else ()
+        """Respawn or heal: ``new_node_id`` in place of ``old_node_id``,
+        on the chip the old node held, once it has exited
+        (:meth:`Launcher.relaunch`)."""
         try:
-            handle = self.launcher.launch(new_node_id, avoid=avoid)
+            handle = self.launcher.relaunch(self.handles.get(old_node_id),
+                                            new_node_id)
         except Exception:
             return False
         with self._lock:  # close()/orphaned() snapshot under it
             self.handles[new_node_id] = handle
-        if old is not None:
-            try:
-                old.kill()  # best effort; it never joined the network
-            except Exception:
-                pass
         return True
+
+    def _node_running(self, node_id: str) -> bool | None:
+        """Whether the node's process still runs (None: no handle)."""
+        handle = self.handles.get(node_id)
+        return None if handle is None else handle.poll() is None
 
     # -- jobs ---------------------------------------------------------------
 

@@ -208,6 +208,7 @@ class ProcessClusterApplication:
             ),
             expected_nodes=node_ids,
             relaunch=self._relaunch,
+            node_running=self._node_running,
             telemetry=self.telemetry,
             conn_wrapper=conn_wrapper,
         )
@@ -219,6 +220,7 @@ class ProcessClusterApplication:
         # The bind address goes through verbatim: each launcher knows how to
         # resolve an unroutable "0.0.0.0" (loopback for local launchers; an
         # SSHLauncher keeps its explicitly configured connect_host).
+        self.launcher.plan(len(node_ids))
         self.launcher.prepare(self.bind_host, self.host_loader.port)
         for node_id in node_ids:
             self.handles[node_id] = self.launcher.launch(node_id)
@@ -229,20 +231,17 @@ class ProcessClusterApplication:
         """Placement-policy callback: a launch never registered — retire it
         and start a replacement, steering clear of the machine that already
         swallowed one launch."""
-        old = self.handles.get(old_node_id)
-        avoid = (old.where,) if old is not None else ()
         try:
-            self.handles[new_node_id] = self.launcher.launch(
-                new_node_id, avoid=avoid
-            )
+            self.handles[new_node_id] = self.launcher.relaunch(
+                self.handles.get(old_node_id), new_node_id)
         except Exception:
             return False
-        if old is not None:
-            try:
-                old.kill()  # best effort; it never joined the network
-            except Exception:
-                pass
         return True
+
+    def _node_running(self, node_id: str) -> bool | None:
+        """Whether the node's process still runs (None: no handle)."""
+        handle = self.handles.get(node_id)
+        return None if handle is None else handle.poll() is None
 
     def run(self) -> Any:
         if self._ran:

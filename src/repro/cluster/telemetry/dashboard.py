@@ -131,7 +131,8 @@ function render(snap, how) {
   document.getElementById("nodes").innerHTML = table(
     [["node"], ["state"], ["items", "num"], ["credits", "num"],
      ["sent", "num"], ["recv", "num"], ["peer out/in", "num"],
-     ["blocks p/h", "num"], ["boot ms", "num"], ["cache h/m", "num"]],
+     ["blocks p/h", "num"], ["chip", "num"], ["boot ms", "num"],
+     ["cache h/m", "num"]],
     nodes.map(([id, n]) => {
       const w = n.wire || {}, r = n.report || {};
       return [[esc(id)], [state(n.state || "?")], [fmt(n.items), "num"],
@@ -139,6 +140,7 @@ function render(snap, how) {
         [bytes(w.bytes_recv), "num"],
         [`${bytes(r.peer_bytes_sent ?? 0)}/${bytes(r.peer_bytes_recv ?? 0)}`, "num"],
         [`${fmt(r.blocks_fetched_from_peers ?? 0)}/${fmt(r.blocks_fetched_from_host ?? 0)}`, "num"],
+        [fmt(r.chip), "num"],
         [fmt(r.boot_ms), "num"],
         [`${fmt(r.cache_hits ?? 0)}/${fmt(r.cache_misses ?? 0)}`, "num"]];
     }));

@@ -12,6 +12,7 @@ same launcher against a real loopback sshd.
 import os
 import socket
 import stat
+import subprocess
 import sys
 import threading
 import time
@@ -27,9 +28,12 @@ from repro.cluster.deploy import (
 from repro.cluster.deploy.base import NodeHandle
 from repro.cluster.deploy.local import (
     DEFAULT_COMPILE_CACHE,
+    TPU_PROCESS_PORT_BASE,
     _child_env,
     compile_cache_dir,
     jax_node_env,
+    tpu_chip_count,
+    tpu_chip_env,
 )
 from repro.cluster.membership import (
     DONE,
@@ -39,6 +43,7 @@ from repro.cluster.membership import (
     Membership,
 )
 from repro.cluster.node_loader import connect_with_retry
+from repro.cluster.service import ClusterService
 from repro.core.builder import ClusterBuilder
 from repro.core.dsl import ClusterSpec
 from repro.core.processes import EmitDetails, ResultDetails
@@ -543,3 +548,236 @@ def test_env_compile_cache_dir_wins_over_compile_cache_dir(monkeypatch,
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     assert compile_cache_dir() == DEFAULT_COMPILE_CACHE \
         == os.path.join(root, "results", "xla_cache")
+
+
+# ---------------------------------------------------------------------------
+# chip binding: one TPU chip a node in a pool of several
+# ---------------------------------------------------------------------------
+
+BINDING = ("TPU_VISIBLE_CHIPS", "TPU_CHIPS_PER_PROCESS_BOUNDS",
+           "TPU_PROCESS_BOUNDS", "TPU_PROCESS_PORT",
+           "ALLOW_MULTIPLE_LIBTPU_LOAD")
+
+
+@pytest.mark.parametrize("slot", [0, 1, 2, 3])
+def test_each_slot_of_a_four_chip_host_sees_its_own_chip(slot):
+    env = tpu_chip_env(slot, 4, {})
+    assert env == {
+        "TPU_VISIBLE_CHIPS": str(slot),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_PORT": str(TPU_PROCESS_PORT_BASE + slot),
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
+    others = [tpu_chip_env(s, 4, {}) for s in range(4) if s != slot]
+    for key in ("TPU_VISIBLE_CHIPS", "TPU_PROCESS_PORT"):
+        assert env[key] not in {o[key] for o in others}
+
+
+@pytest.mark.parametrize("chips,caller", [
+    (0, {}),                                # no TPU on this machine
+    (1, {}),                                # one chip: the node owns it
+    (4, {"JAX_PLATFORMS": "cpu"}),          # the caller keeps JAX on the CPU
+    (4, {"TPU_VISIBLE_CHIPS": "2,3"}),      # the caller chose the chips
+], ids=["no_chips", "one_chip", "jax_on_cpu", "caller_chips"])
+def test_no_binding_applies(chips, caller):
+    assert tpu_chip_env(0, chips, caller) == {}
+    assert tpu_chip_env(1, chips, caller) == {}
+
+
+@pytest.mark.parametrize("slot", [4, 5])
+def test_a_node_beyond_the_chips_runs_on_the_cpu(slot):
+    assert tpu_chip_env(slot, 4, {}) == {"JAX_PLATFORMS": "cpu"}
+
+
+@pytest.mark.parametrize("files,chips", [
+    (["accel0", "accel1", "accel2", "accel3"], 4),    # TPU v4 and earlier
+    (["vfio/vfio", "vfio/0", "vfio/1", "vfio/2", "vfio/3"], 4),  # v5e
+    (["vfio/vfio", "vfio/0"], 1),
+    (["null", "vfio/vfio"], 0),
+    ([], 0),
+], ids=["accel", "vfio", "vfio_one", "vfio_control_only", "none"])
+def test_the_launcher_counts_chips_from_device_files(tmp_path, files, chips):
+    for name in files:
+        (tmp_path / name).parent.mkdir(parents=True, exist_ok=True)
+        (tmp_path / name).touch()
+    assert tpu_chip_count(str(tmp_path)) == chips
+
+
+class FakeProc:
+    """A node-loader process that records what happens to it: ``events``
+    gets ("kill", id) and ("wait", id); it exits when killed unless
+    ``stuck``, and ``exit()`` ends it as a crash would."""
+
+    def __init__(self, node_id, events, env):
+        self.node_id, self.events, self.env = node_id, events, env
+        self.stdout = self.stderr = None
+        self.returncode = None
+        self.stuck = False
+
+    def poll(self):
+        return self.returncode
+
+    def wait(self, timeout=None):
+        self.events.append(("wait", self.node_id))
+        if self.returncode is None:
+            raise subprocess.TimeoutExpired("node", timeout)
+        return self.returncode
+
+    def kill(self):
+        self.events.append(("kill", self.node_id))
+        if not self.stuck:
+            self.returncode = -9
+
+    def exit(self):
+        self.returncode = 1
+
+
+@pytest.fixture
+def fake_host(monkeypatch):
+    """A host of four TPU chips whose node-loaders are :class:`FakeProc`:
+    node id -> its process (the latest of that id), and the events."""
+    from repro.cluster.deploy import local
+
+    procs, events = {}, []
+
+    def spawn(host, port, node_id, *, python, preload, compile_cache_dir,
+              env):
+        events.append(("spawn", node_id))
+        procs[node_id] = FakeProc(node_id, events,
+                                  local._child_env(compile_cache_dir, env))
+        return procs[node_id]
+
+    for key in BINDING + ("JAX_PLATFORMS",):
+        monkeypatch.delenv(key, raising=False)
+    monkeypatch.setattr(local, "tpu_chip_count", lambda dev="/dev": 4)
+    monkeypatch.setattr(local, "spawn_node_loader", spawn)
+    return procs, events
+
+
+def _pool(nodes):
+    """A service on the fake host with its ``nodes`` launched, not
+    started: the launcher's and ``_relaunch``'s part alone."""
+    svc = ClusterService(nodes=nodes, launcher=LocalLauncher())
+    svc.launcher.plan(nodes)
+    svc.launcher.prepare("127.0.0.1", 2000)
+    for i in range(nodes):
+        svc.handles[f"node{i}"] = svc.launcher.launch(f"node{i}")
+    return svc
+
+
+def _live_chips(svc):
+    return sorted(h.chip for h in svc.handles.values() if h.poll() is None)
+
+
+def test_a_pool_of_four_binds_node_k_to_chip_k(fake_host):
+    procs, _ = fake_host
+    svc = _pool(4)
+    for i in range(4):
+        assert svc.handles[f"node{i}"].chip == i
+        env = procs[f"node{i}"].env
+        assert env["TPU_VISIBLE_CHIPS"] == str(i)
+        assert env["TPU_PROCESS_PORT"] == str(TPU_PROCESS_PORT_BASE + i)
+        assert "JAX_PLATFORMS" not in env
+
+
+def test_a_pool_of_one_node_is_launched_without_any_binding(fake_host):
+    procs, _ = fake_host
+    svc = _pool(1)
+    assert svc.handles["node0"].chip is None
+    assert not set(BINDING + ("JAX_PLATFORMS",)) & set(procs["node0"].env)
+
+
+def test_nodes_beyond_the_chips_run_on_the_cpu_and_are_logged_once(
+        fake_host, caplog):
+    procs, _ = fake_host
+    with caplog.at_level("WARNING", logger="repro.cluster.deploy.local"):
+        svc = _pool(6)
+    assert [svc.handles[f"node{i}"].chip for i in range(4)] == [0, 1, 2, 3]
+    for nid in ("node4", "node5"):
+        assert svc.handles[nid].chip is None
+        assert procs[nid].env["JAX_PLATFORMS"] == "cpu"
+        assert "TPU_VISIBLE_CHIPS" not in procs[nid].env
+    [record] = caplog.records
+    assert "node4 and later nodes run on the CPU" in record.getMessage()
+
+
+def test_a_relaunch_takes_the_dead_nodes_chip_after_its_handle_exited(
+        fake_host):
+    procs, events = fake_host
+    svc = _pool(4)
+    old = procs["node1"]
+    del events[:]
+    assert svc._relaunch("node1", "node1r2")
+    # The old node is killed and waited for before its replacement starts.
+    assert events == [("kill", "node1"), ("wait", "node1"),
+                      ("spawn", "node1r2")]
+    assert old.returncode is not None
+    assert svc.handles["node1r2"].chip == 1
+    assert procs["node1r2"].env["TPU_VISIBLE_CHIPS"] == "1"
+
+
+def test_a_relaunch_never_shares_the_chip_of_a_node_that_did_not_exit(
+        fake_host):
+    procs, events = fake_host
+    svc = _pool(4)
+    procs["node2"].stuck = True  # survives its kill past the wait
+    assert svc._relaunch("node2", "node2r2")
+    assert ("wait", "node2") in events
+    assert svc.handles["node2r2"].chip is None
+    assert procs["node2r2"].env["JAX_PLATFORMS"] == "cpu"
+    # Once it has exited, the next launch gets the chip.
+    procs["node2"].returncode = -9
+    assert svc.launcher.launch("node4").chip == 2
+
+
+def test_a_healing_pool_keeps_one_chip_a_node(fake_host):
+    procs, _ = fake_host
+    svc = _pool(4)
+    for dead, new in [("node3", "node3r2"), ("node0", "node0r2"),
+                      ("node3r2", "node3r3")]:
+        procs[dead].exit()  # it crashed: the host healed it
+        assert svc._relaunch(dead, new)
+        assert _live_chips(svc) == [0, 1, 2, 3]
+    assert svc.handles["node0r2"].chip == 0
+    assert svc.handles["node3r3"].chip == 3
+
+
+def test_an_unbound_relaunch_starts_the_replacement_first(fake_host,
+                                                          monkeypatch):
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    procs, events = fake_host
+    svc = _pool(2)
+    del events[:]
+    assert svc._relaunch("node1", "node1r2")
+    assert events == [("spawn", "node1r2"), ("kill", "node1")]
+    assert svc.handles["node1r2"].chip is None
+
+
+def test_racing_launches_never_share_a_chip(fake_host):
+    """Boot, heals and growth launch from different threads: under racing
+    launches each chip still goes to one node, the rest to the CPU."""
+    launcher = LocalLauncher()
+    launcher.plan(4)
+    launcher.prepare("127.0.0.1", 2000)
+    handles = []
+    start = threading.Barrier(os.cpu_count() + 5)
+
+    def launch(i):
+        start.wait(timeout=10)
+        handles.append(launcher.launch(f"node{i}"))
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=launch, args=(i,))
+                   for i in range(start.parties)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=10)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    chips = sorted(h.chip for h in handles if h.chip is not None)
+    assert chips == [0, 1, 2, 3] and len(handles) == start.parties

@@ -341,3 +341,59 @@ def test_pool_job_honours_stage_prefetch_cap():
         assert h.result() == [2 * i for i in range(40)]
         assert svc.host_loader.stats.max_batch <= 2  # pool_workers + 0
     assert svc.orphaned() == []
+
+
+def test_the_host_logs_each_node_death_with_its_state(caplog):
+    """A node declared dead is logged with its id, its silence, its items
+    in flight and whether its process still runs; the bus's failure event
+    carries the same."""
+
+    def slow_double(x):
+        time.sleep(0.01)
+        return x * 2
+
+    n = 60
+    with caplog.at_level("WARNING", logger="repro.cluster.host_loader"):
+        with _service() as svc:
+            h = svc.submit(_spec(slow_double, n), timeout=120)
+            hl = svc.host_loader
+            deadline = time.monotonic() + 30
+            while hl.stats.items_total < 10:
+                assert time.monotonic() < deadline
+                time.sleep(0.005)
+            svc.kill_node("node1")
+            assert h.result() == [2 * i for i in range(n)]
+            events = svc.telemetry.events_since(0)
+    [record] = caplog.records
+    msg = record.getMessage()
+    assert msg.startswith("node node1 declared dead: ")
+    assert " s since its last heartbeat, " in msg
+    assert " items in flight, process " in msg
+    assert msg.endswith(("still running", "exited"))
+    [failure] = [e for e in events if e["kind"] == "failure"]
+    assert failure["node"] == "node1"
+    assert failure["items_in_flight"] >= 0
+    assert failure["process_running"] in (True, False)
+
+
+@pytest.mark.parametrize("visible,chip", [
+    ("", None), ("2", 2), ("0,1", None)])
+def test_a_node_reports_the_chip_it_was_bound_to(visible, chip):
+    from repro.cluster.node_loader import bound_chip
+
+    env = {"TPU_VISIBLE_CHIPS": visible} if visible else {}
+    assert bound_chip(env) == chip
+
+
+def test_the_pool_snapshot_shows_each_nodes_chip():
+    with _service() as svc:
+        svc.submit(_spec(_double, 8), timeout=60).result()
+        deadline = time.monotonic() + 10
+        while True:
+            nodes = svc.metrics_snapshot()["nodes"]
+            reports = [n.get("report") or {} for n in nodes.values()]
+            if len(reports) == 2 and all("chip" in r for r in reports):
+                break
+            assert time.monotonic() < deadline
+            time.sleep(0.05)
+        assert [r["chip"] for r in reports] == [None, None]
